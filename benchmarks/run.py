@@ -141,6 +141,9 @@ def main() -> None:
     if args.devices is not None:
         _force_devices(args.devices)
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (estimators_bench, kernels_bench, kmeans_batched_bench,
                    lint_bench, paper_figs, serving_bench, trials_bench)
 

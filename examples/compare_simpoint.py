@@ -23,6 +23,7 @@ import sys
 from repro.core.sampling import (BBVClusters, Centroid, RankedSetUnit,
                                  RFVClusters, SamplingPlan)
 from repro.experiments import ExperimentEngine, SweepSpec, run_sweep
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.simcpu import APP_NAMES, CONFIGS
 
 PLANS = {
@@ -36,6 +37,7 @@ PLANS = {
 def main() -> None:
     arg = sys.argv[1] if len(sys.argv) > 1 else "557.xz_r"
     apps = tuple(APP_NAMES) if arg == "all" else (arg,)
+    enable_compile_cache()
     engine = ExperimentEngine.auto()
     if engine.mesh is not None:
         print(f"# app axis sharded over {engine.mesh.devices.size} devices")

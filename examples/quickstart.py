@@ -22,12 +22,14 @@ from repro.core.sampling import (Centroid, RFVClusters, SamplingPlan,
                                  Stratification, TwoPhaseFlow, srs_estimate)
 from repro.experiments import (ExperimentEngine, TrialSpec, plan_selection,
                                run_trials)
+from repro.runtime.compile_cache import enable_compile_cache
 
 APP = "502.gcc_r"          # the paper's hardest application
 NUM_STRATA = 20
 
 
 def main() -> None:
+    enable_compile_cache()
     engine = ExperimentEngine()
     # ONE stacked build: census truth, phase-1 SRS, BBV/RFV/DG strata.
     # (Add more app names — or mesh=make_app_mesh() — and the same call

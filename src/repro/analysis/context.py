@@ -38,7 +38,7 @@ TRACE_WRAPPERS = frozenset({
     "jax.grad", "jax.value_and_grad", "jax.lax.scan", "jax.lax.map",
     "jax.lax.while_loop", "jax.lax.cond", "jax.lax.fori_loop",
     "jax.lax.switch", "jax.lax.associative_scan",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
     "jax.experimental.pallas.pallas_call",
 })
 # unambiguous last components: anything.pallas_call / anything.shard_map

@@ -22,9 +22,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .kmeans import _assign_jnp
 
-# version-compat shard_map shim shared with the app-axis sharding helpers
-from ...distributed.appaxis import shard_map as _shard_map
-
 
 def _local_stats(x, centroids, k):
     labels, min_d2 = _assign_jnp(x, centroids)
@@ -43,7 +40,7 @@ def make_distributed_kmeans_step(mesh: Mesh, data_axes: Sequence[str], k: int):
     axes = tuple(data_axes)
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes), P()),
         out_specs=(P(), P()),
     )
@@ -64,7 +61,7 @@ def make_distributed_assign(mesh: Mesh, data_axes: Sequence[str]):
     axes = tuple(data_axes)
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes), P()),
         out_specs=P(axes),
     )

@@ -4,12 +4,15 @@ Design notes
 ------------
 * kmeans++ initialization, Lloyd iterations inside ``lax.while_loop`` —
   the whole fit is one jitted computation.
-* Pluggable assignment backend: ``"jnp"`` (pure jnp, the oracle) or
+* Pluggable assignment backend: ``"jnp"`` (pure jnp, the oracle),
   ``"pallas"`` (the batch-native tiled TPU kernel in
-  ``repro.kernels.kmeans_assign``). Requesting ``"pallas"`` off-TPU falls
-  back with a one-time ``BackendFallbackWarning`` naming the reason
-  (platform → interpret mode, import failure → jnp oracle); the backend
-  that actually ran is recorded on every fit result.
+  ``repro.kernels.kmeans_assign``) or ``"auto"``, the default: the kernel
+  on TPU, the oracle elsewhere. (On a TPU the oracle's XLA einsum runs
+  below f32 precision and its Lloyd loop does not converge on the BBV
+  bank, so fits on the chip take the kernel.) Requesting ``"pallas"``
+  off-TPU falls back with a one-time ``BackendFallbackWarning`` naming
+  the reason (platform → interpret mode, import failure → jnp oracle);
+  the backend that actually ran is recorded on every fit result.
 * Empty clusters are re-seeded to the point farthest from its centroid —
   standard practice; keeps L strata non-empty, which the stratified
   estimators require.
@@ -58,13 +61,14 @@ def resolve_backend(requested: str) -> ResolvedBackend:
     ``"jnp"`` always resolves to itself. ``"pallas"`` resolves to
     ``"pallas"`` on TPU, to ``"pallas_interpret"`` (same kernel, Pallas
     interpreter — correctness validation, not speed) on other platforms,
-    and to ``"jnp"`` when the kernel package cannot be imported. Any
-    fallback emits a one-time ``BackendFallbackWarning`` naming the
+    and to ``"jnp"`` when the kernel package cannot be imported off-TPU.
+    ``"auto"`` resolves to ``"pallas"`` on TPU and to ``"jnp"`` elsewhere.
+    Any fallback emits a one-time ``BackendFallbackWarning`` naming the
     reason (shared policy: ``repro.kernels.backend``).
     """
-    if requested not in ("jnp", "pallas"):
+    if requested not in ("jnp", "pallas", "auto"):
         raise ValueError(f"unknown backend {requested!r}; "
-                         "expected 'jnp' or 'pallas'")
+                         "expected 'jnp', 'pallas' or 'auto'")
     return _resolve_shared(requested, kernel="k-means assignment",
                            import_probe=_probe_kmeans_kernel)
 
@@ -257,7 +261,7 @@ def kmeans_batch(
     keys=None,
     seeds=None,
     max_iters: int = 100,
-    backend: str = "jnp",
+    backend: str = "auto",
     tol: float = 1e-8,
 ) -> list[KMeansResult]:
     """Batched k-means: one fit per key/seed as a single stacked program.
@@ -295,7 +299,7 @@ def kmeans(
     key: Optional[jax.Array] = None,
     seed: int = 0,
     max_iters: int = 100,
-    backend: str = "jnp",
+    backend: str = "auto",
     tol: float = 1e-8,
     restarts: int = 1,
 ) -> KMeansResult:
@@ -343,7 +347,7 @@ def kmeans_multi_seed(
     *,
     seeds,
     max_iters: int = 100,
-    backend: str = "jnp",
+    backend: str = "auto",
 ) -> list[KMeansResult]:
     """One fit per seed (the paper's 10-seed repetitions for Figs 7-8),
     batched into a single stacked computation."""
@@ -391,7 +395,7 @@ def kmeans_bank(
     key: Optional[jax.Array] = None,
     seed: int = 0,
     max_iters: int = 100,
-    backend: str = "jnp",
+    backend: str = "auto",
     tol: float = 1e-8,
     mesh=None,
 ) -> KMeansBank:

@@ -37,4 +37,6 @@ def random_project(
         norm = jnp.maximum(jnp.abs(x).sum(axis=1, keepdims=True), 1e-12)
         x = x / norm
     proj = projection_matrix(key, x.shape[1], d_out, x.dtype)
-    return x @ proj
+    # full f32: the TPU's default matmul precision would give the chip
+    # different BBV features (and strata) than every other backend
+    return jnp.matmul(x, proj, precision=jax.lax.Precision.HIGHEST)

@@ -82,8 +82,8 @@ class PrecisionPolicy:
         """
         if not self.needs_x64:
             return contextlib.nullcontext()
-        from jax.experimental import enable_x64
-        return enable_x64(True)
+        import jax
+        return jax.enable_x64(True)
 
     # canonical policies ----------------------------------------------------
     @classmethod

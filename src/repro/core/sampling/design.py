@@ -108,7 +108,7 @@ class TwoPhaseFlow:
                 raise ValueError("string schemes need num_strata")
             scheme = _plan.make_stratifier(
                 scheme, num_strata=num_strata, seed=seed or 0,
-                backend=kmeans_backend or "jnp")
+                backend=kmeans_backend or "auto")
         else:
             for arg, field, val in (("num_strata", "num_strata", num_strata),
                                     ("seed", "seed", seed),

@@ -256,7 +256,7 @@ class BBVClusters(Stratifier):
     pool_kind: ClassVar[str] = "census"
 
     restarts: int = 3
-    backend: str = "jnp"
+    backend: str = "auto"
 
     def resolve(self, exps: Sequence) -> StratumBank:
         """Stack the engine's census-BBV artifacts over apps."""
@@ -285,7 +285,7 @@ class RFVClusters(Stratifier):
     pool_kind: ClassVar[str] = "phase1"
 
     restarts: int = 3
-    backend: str = "jnp"
+    backend: str = "auto"
 
     def resolve(self, exps: Sequence) -> StratumBank:
         """Stack the engine's phase-1 RFV artifacts over apps."""
@@ -531,19 +531,20 @@ class Centroid(SelectionPolicy):
         """Argmin of squared feature distance to the centroid, per
         stratum (masked to members; empty strata are masked out)."""
         xp = _tables._ns(ctx.feats, ctx.centroids)
-        # the expanded |x|^2 - 2<x,c> + |c|^2 form cancels catastrophically
-        # in float32 at census scale (d2 ~ 1e-5 out of O(1) terms), enough
-        # to flip near-boundary argmins between backends/compilations —
-        # accumulate in the namespace's widest float (f64 on the host and
+        # each member's distance to its OWN stratum centroid, in the direct
+        # sum((x - c)^2) form: the expanded |x|^2 - 2<x,c> + |c|^2 form
+        # cancels catastrophically in float32 at census scale (d2 ~ 1e-5
+        # out of O(1) terms) and its matmul runs at reduced precision on
+        # the TPU, enough to flip near-boundary argmins between backends.
+        # Accumulate in the namespace's widest float (f64 on the host and
         # under x64; the canonical float via result_type(0.0) never warns)
         dt = xp.result_type(0.0)
         feats = xp.asarray(ctx.feats, dt)
         cents = xp.asarray(ctx.centroids, dt)
-        x2 = (feats ** 2).sum(axis=2)                       # (A, n)
-        c2 = (cents ** 2).sum(axis=2)                       # (A, L)
-        d2 = x2[:, :, None] - 2.0 * xp.einsum(
-            "and,ald->anl", feats, cents) + c2[:, None, :]
-        return xp.where(ctx.member, d2, xp.inf).argmin(axis=1)
+        own = xp.clip(ctx.labels, 0, ctx.num_strata - 1)
+        diff = feats - cents[xp.arange(own.shape[0])[:, None], own]
+        d2 = (diff ** 2).sum(axis=2)                        # (A, n)
+        return xp.where(ctx.member, d2[:, :, None], xp.inf).argmin(axis=1)
 
     def select_local(self, labels, *, features, centroids, baseline,
                      num_strata: int, seed: int = 0,
@@ -739,7 +740,7 @@ def _x64_sweep_programs() -> bool:
 
     Delegates to ``PrecisionPolicy.host_parity`` — the ONE precision
     policy (``repro.core.precision``): CPU hosts trace the program under
-    ``jax.experimental.enable_x64`` so on-device estimates match the
+    ``jax.enable_x64`` so on-device estimates match the
     historic float64 host reduction to rounding; TPU backends (no
     native f64) keep the default float32.
     """

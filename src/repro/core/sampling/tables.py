@@ -53,6 +53,8 @@ __all__ = [
     "two_phase_variance",
     "collapse_small_strata",
     "collapsed_pairs_variance",
+    "collapsed_pairs_groups",
+    "collapsed_pairs_grouped",
     "proportional_allocation",
     "neyman_allocation",
     "masked_srs_stats",
@@ -528,29 +530,64 @@ def collapsed_pairs_variance(y_sorted, w_sorted, n_valid, *,
     with n_h = 1. Returns ``(variance, df)`` — both NaN for lanes with
     V < 2; ``df = V − ⌊V/2⌋`` ([18]: L − J).
     """
-    xp = _ns(y_sorted, w_sorted, n_valid)
+    return collapsed_pairs_grouped(
+        y_sorted, collapsed_pairs_groups(w_sorted, n_valid,
+                                         num_strata=num_strata), n_valid)
+
+
+def collapsed_pairs_groups(w_sorted, n_valid, *, num_strata: int):
+    """The y-free half of ``collapsed_pairs_variance``: per neighbor
+    group ``j`` of the sorted order, ``(wsq, in_grp, has3)`` — each
+    ``(..., J)`` with ``J = L // 2`` — the group's summed squared
+    weights, whether it is one of the lane's ``⌊V/2⌋`` groups, and
+    whether it is the final triple of an odd V.
+
+    The Monte-Carlo engine computes these once per app on the host: the
+    same weight arithmetic inside every trial program would be fused
+    (and rounded) differently per program shape.
+    """
+    xp = _ns(w_sorted, n_valid)
     L = int(num_strata)
     v_cnt = xp.asarray(n_valid)
     n_groups = v_cnt // 2
     odd = (v_cnt % 2) == 1
-    var = xp.zeros(xp.broadcast_shapes(
-        xp.shape(y_sorted)[:-1], xp.shape(w_sorted)[:-1],
-        xp.shape(v_cnt)), dtype=xp.asarray(y_sorted).dtype)
-    for j in range(max(L // 2, 1)):
+    shape = xp.broadcast_shapes(xp.shape(w_sorted)[:-1], xp.shape(v_cnt))
+    wsq, in_grp, has3 = [], [], []
+    for j in range(L // 2):
         p1, p2, p3 = 2 * j, 2 * j + 1, min(2 * j + 2, L - 1)
-        if p2 >= L:
-            break
-        in_grp = j < n_groups
-        has3 = odd & (j == n_groups - 1)
-        y1, y2, y3 = (y_sorted[..., p] for p in (p1, p2, p3))
+        tri = odd & (j == n_groups - 1)
         w1, w2, w3 = (w_sorted[..., p] for p in (p1, p2, p3))
+        wsq.append(xp.broadcast_to(
+            w1 ** 2 + w2 ** 2 + xp.where(tri, w3 ** 2, 0.0), shape))
+        in_grp.append(xp.broadcast_to(j < n_groups, shape))
+        has3.append(xp.broadcast_to(tri, shape))
+    if not wsq:                               # L < 2: no groups
+        return (xp.zeros(shape + (0,), xp.asarray(w_sorted).dtype),
+                xp.zeros(shape + (0,), bool), xp.zeros(shape + (0,), bool))
+    return tuple(xp.stack(g, axis=-1) for g in (wsq, in_grp, has3))
+
+
+def collapsed_pairs_grouped(y_sorted, groups, n_valid):
+    """``collapsed_pairs_variance`` from precomputed
+    ``collapsed_pairs_groups``; ``y_sorted``: ``(..., L)``, groups and
+    ``n_valid`` broadcastable against its leading axes."""
+    wsq_g, in_grp_g, has3_g = groups
+    xp = _ns(y_sorted, wsq_g, n_valid)
+    L = xp.shape(y_sorted)[-1]
+    v_cnt = xp.asarray(n_valid)
+    var = xp.zeros(xp.broadcast_shapes(
+        xp.shape(y_sorted)[:-1], xp.shape(wsq_g)[:-1],
+        xp.shape(v_cnt)), dtype=xp.asarray(y_sorted).dtype)
+    for j in range(xp.shape(wsq_g)[-1]):
+        p1, p2, p3 = 2 * j, 2 * j + 1, min(2 * j + 2, L - 1)
+        y1, y2, y3 = (y_sorted[..., p] for p in (p1, p2, p3))
         s2_pair = (y1 - y2) ** 2 / 4.0
         m3 = (y1 + y2 + y3) / 3.0
         s2_tri = ((y1 - m3) ** 2 + (y2 - m3) ** 2 + (y3 - m3) ** 2) / 2.0
-        s2 = xp.where(has3, s2_tri, s2_pair)
-        wsq = w1 ** 2 + w2 ** 2 + xp.where(has3, w3 ** 2, 0.0)
-        var = var + xp.where(in_grp, wsq * s2, 0.0)
+        s2 = xp.where(has3_g[..., j], s2_tri, s2_pair)
+        var = var + xp.where(in_grp_g[..., j], wsq_g[..., j] * s2, 0.0)
     bad = v_cnt < 2
+    n_groups = v_cnt // 2
     var = xp.where(bad, xp.nan, var)
     df = xp.where(bad, xp.nan, (v_cnt - n_groups).astype(var.dtype))
     return var, df

@@ -33,13 +33,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax >= 0.5 promotes shard_map to the top-level namespace; 0.4.x only has
-# the experimental home. Support both (shared by repro.core.clustering too).
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
 
 def app_axis_name(mesh: Mesh) -> str:
     if len(mesh.axis_names) != 1:
@@ -96,10 +89,9 @@ def make_app_sharded(fn: Callable, mesh: Mesh,
     @functools.lru_cache(maxsize=8)
     def build(n_args: int):
         in_specs = tuple(P() if i in rep else P(axis) for i in range(n_args))
-        # check_rep=False: jax 0.4.x has no replication rule for while_loop
-        # (the k-means Lloyd loop); lanes are independent so it is vacuous
-        return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=P(axis), check_rep=False))
+        # lanes are independent, so the varying-axis check is vacuous
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=P(axis), check_vma=False))
 
     def call(*args: Any):
         a_size = next(np.shape(a)[0] for i, a in enumerate(args)
@@ -149,8 +141,8 @@ def make_app_trial_sharded(fn: Callable, mesh: Mesh,
     @functools.lru_cache(maxsize=8)
     def build(n_args: int):
         in_specs = tuple(P() if i in rep else P(app) for i in range(n_args))
-        return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False))
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
 
     def call(*args: Any):
         a_size = next(np.shape(a)[0] for i, a in enumerate(args)

@@ -79,6 +79,10 @@ class AppExperiment:
     dg_labels: np.ndarray
     dg_weights: np.ndarray
     num_strata: int = NUM_STRATA
+    # Lloyd iterations of the BBV / RFV k-means fits (the fit's
+    # max_iters means it stopped at the cap, not at convergence)
+    bbv_iterations: int = 0
+    rfv_iterations: int = 0
 
     def cpi(self, cfg_i: int, indices) -> np.ndarray:
         """(n,) CPI for one config, through the memo table."""
@@ -381,7 +385,9 @@ class ExperimentEngine:
                 rfv_z=zr[a, :n1_a],
                 rfv_labels=rfv_fit.labels[a, :n1_a], rfv_weights=rfv_w[a],
                 rfv_centroids=rfv_fit.centroids[a],
-                dg_labels=dg_list[a], dg_weights=dg_w[a], num_strata=L)
+                dg_labels=dg_list[a], dg_weights=dg_w[a], num_strata=L,
+                bbv_iterations=int(bbv_fit.iterations[a]),
+                rfv_iterations=int(rfv_fit.iterations[a]))
 
     # multi-seed stratification (paper Figs 7-8): one vmapped computation
     def rfv_stratifications(self, name: str, seeds: Sequence[int]):

@@ -224,16 +224,15 @@ def fused_sweep_program(plan: sampling_plan.SamplingPlan,
     if mesh is None:
         return jax.jit(traced, donate_argnums=_DONATE)
 
-    from ..distributed.appaxis import (app_trial_axes, pad_app_axis,
-                                       shard_map)
+    from ..distributed.appaxis import app_trial_axes, pad_app_axis
     from jax.sharding import PartitionSpec as P
 
     axis, _ = app_trial_axes(mesh)
     n_dev = int(mesh.shape[axis])
     in_specs = tuple(P() if i in _REPLICATED else P(axis)
                      for i in range(13))
-    prog = jax.jit(shard_map(traced, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(axis), check_rep=False),
+    prog = jax.jit(jax.shard_map(traced, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(axis), check_vma=False),
                    donate_argnums=_DONATE)
 
     def call(*args):

@@ -222,18 +222,35 @@ def trial_uniforms(spec: TrialSpec, scheme: str, num_apps: int,
     return np.asarray(u[:, :spec.trials])
 
 
+def _gather_lanes(table, idx):
+    """(A, W) per-app table at (A, T, k) indices -> (A, T, k), gathered
+    per app: the table is never broadcast over the trial axis, which
+    would materialize A x T x W values (tens of GB for a census pool)."""
+    return jnp.take_along_axis(table[:, None, :], idx, axis=2)
+
+
+def _sum_draws(x):
+    """Sum over the last (draw or stratum) axis, left to right, as a
+    scan of elementwise adds. An XLA reduce picks its summation order
+    per program shape, backend and host vector width, so per-trial
+    outcomes would differ in the last bit between chunkings, meshes and
+    machines; this order is the same in every program, and the program
+    does not grow with the number of draws."""
+    xs = jnp.moveaxis(x, -1, 0)
+    total, _ = jax.lax.scan(lambda acc, xi: (acc + xi, None), xs[0], xs[1:])
+    return total
+
+
 def _srs_chunk(u, truth, crit, pool, n_valid):
     """(A, Tc, n) uniforms x (A, N) value pool -> per-trial estimate,
     percent error, eq. (2) t-interval half-width and CI-covers-truth."""
-    a, t, n = u.shape
+    n = u.shape[2]
     idx = jnp.minimum((u * n_valid[:, None, None]).astype(jnp.int32),
                       (n_valid - 1)[:, None, None].astype(jnp.int32))
-    vals = jnp.take_along_axis(
-        jnp.broadcast_to(pool[:, None, :], (a, t, pool.shape[1])), idx,
-        axis=2)
-    est = vals.mean(axis=2)
+    vals = _gather_lanes(pool, idx)
+    est = _sum_draws(vals) / n
     err = 100.0 * jnp.abs(est - truth[:, None]) / truth[:, None]
-    ss = ((vals - est[:, :, None]) ** 2).sum(axis=2)
+    ss = _sum_draws((vals - est[:, :, None]) ** 2)
     v_mean = jnp.where(n > 1, ss / max(n - 1, 1), jnp.nan) / n
     half = crit[:, None] * jnp.sqrt(v_mean)
     covered = jnp.abs(est - truth[:, None]) <= half
@@ -241,11 +258,12 @@ def _srs_chunk(u, truth, crit, pool, n_valid):
 
 
 def _stratified_chunk(u, truth, crit, sorted_vals, offsets, counts,
-                      weights, key_order, w_sorted, n_occ):
+                      weights, key_order, wsq, in_grp, has3, n_occ):
     """One unit per non-empty stratum per trial, weighted sum (the Fig 8
     estimator: empty strata contribute nothing, no renormalization) —
     plus the eq. (4) collapsed-pairs CI over occupied strata, evaluated
-    lane-wise by ``sampling_tables.collapsed_pairs_variance``."""
+    lane-wise by ``sampling_tables.collapsed_pairs_grouped`` from the
+    app's host-computed ``collapsed_pairs_groups`` (wsq, in_grp, has3)."""
     a, t, l = u.shape
     pick = offsets[:, None, :] + jnp.minimum(
         (u * counts[:, None, :]).astype(jnp.int32),
@@ -253,17 +271,16 @@ def _stratified_chunk(u, truth, crit, sorted_vals, offsets, counts,
     # trailing empty strata put offsets at the row width: clamp explicitly
     # (the pick is zero-weighted via `occupied` below)
     pick = jnp.minimum(pick, sorted_vals.shape[1] - 1)
-    vals = jnp.take_along_axis(
-        jnp.broadcast_to(sorted_vals[:, None, :],
-                         (a, t, sorted_vals.shape[1])), pick, axis=2)
+    vals = _gather_lanes(sorted_vals, pick)
     occupied = (counts > 0)[:, None, :]
-    est = jnp.sum(vals * weights[:, None, :] * occupied, axis=2)
+    est = _sum_draws(vals * weights[:, None, :] * occupied)
     err = 100.0 * jnp.abs(est - truth[:, None]) / truth[:, None]
     # collapsed-pairs CI: stratum draws gathered into key order
     y_sorted = jnp.take_along_axis(
         vals, jnp.broadcast_to(key_order[:, None, :], (a, t, l)), axis=2)
-    var, _ = sampling_tables.collapsed_pairs_variance(
-        y_sorted, w_sorted[:, None, :], n_occ[:, None], num_strata=l)
+    var, _ = sampling_tables.collapsed_pairs_grouped(
+        y_sorted, tuple(g[:, None, :] for g in (wsq, in_grp, has3)),
+        n_occ[:, None])
     half = crit[:, None] * jnp.sqrt(var)
     covered = jnp.abs(est - truth[:, None]) <= half
     return est, err, half, covered
@@ -483,13 +500,15 @@ def _scheme_setup(engine: ExperimentEngine, spec: TrialSpec, apps, mesh,
         key_order = np.argsort(key, axis=1, kind="stable")
         w_sorted = np.take_along_axis(weights, key_order, axis=1)
         n_occ = (counts > 0).sum(axis=1)
+        groups = sampling_tables.collapsed_pairs_groups(
+            w_sorted.astype(tdt), n_occ, num_strata=l_n)
         dfs = np.maximum(n_occ - n_occ // 2, 1).astype(np.float64)
         crit = critical_values(spec.confidence, dfs).astype(tdt)
         setups[scheme] = (_stratified_chunk, l_n, crit,
                           (sorted_vals, offsets.astype(np.int32),
                            counts.astype(np.int32), weights.astype(tdt),
-                           key_order.astype(np.int32), w_sorted.astype(tdt),
-                           n_occ.astype(np.int32)))
+                           key_order.astype(np.int32)) + groups
+                          + (n_occ.astype(np.int32),))
     return truth, pp, setups
 
 

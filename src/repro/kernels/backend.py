@@ -7,7 +7,8 @@ contract (documented in ``docs/kernels.md``):
 * ``"pallas"`` — the TPU kernel as requested. Off-TPU it degrades to the
   Pallas *interpreter* (same kernel body, correctness validation only)
   and on import failure to the oracle — each degradation emits a
-  one-time ``BackendFallbackWarning`` naming the reason.
+  one-time ``BackendFallbackWarning`` naming the reason. On TPU a kernel
+  import failure raises instead of degrading.
 * ``"auto"`` — the production default: the kernel on TPU, the oracle
   elsewhere (interpret mode is far too slow for hot paths). The off-TPU
   choice emits a one-time ``BackendFallbackWarning`` so runs that
@@ -94,7 +95,9 @@ def resolve_backend(requested: str, *, kernel: str,
     * ``"jnp"`` resolves to itself, silently.
     * ``"pallas"`` resolves to ``"pallas"`` on TPU, to
       ``"pallas_interpret"`` elsewhere, and to ``"jnp"`` when the kernel
-      package cannot import — the latter two warn once.
+      package cannot import off-TPU — the latter two warn once. On TPU
+      an import failure raises: the device path never hides behind the
+      oracle.
     * ``"auto"`` resolves to ``"pallas"`` on TPU and to ``"jnp"``
       elsewhere (warning once off-TPU: interpret mode is validation-only,
       not a production path).
@@ -104,14 +107,16 @@ def resolve_backend(requested: str, *, kernel: str,
     if requested not in ("pallas", "auto"):
         raise ValueError(f"unknown backend {requested!r}; "
                          "expected 'jnp', 'pallas' or 'auto'")
+    platform = jax.default_backend()
     try:
         import_probe()
     except Exception as e:  # pragma: no cover - import is cheap and local
+        if platform == "tpu":
+            raise
         reason = (f"import of the {kernel} kernel failed: "
                   f"{type(e).__name__}: {e}")
         warn_fallback_once(kernel, requested, "jnp", reason)
         return ResolvedBackend(requested, "jnp", reason)
-    platform = jax.default_backend()
     if platform == "tpu":
         return ResolvedBackend(requested, "pallas")
     if requested == "auto":

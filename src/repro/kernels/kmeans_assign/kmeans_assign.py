@@ -42,18 +42,20 @@ def _assign_kernel(x_ref, c_ref, c2_ref, labels_ref, mind2_ref):
     """One (batch element, point tile) grid step.
 
     Block shapes: x (1, block_n, d), c (1, k, d), c2 (1, 1, k) — the
-    leading 1 is the batch block; outputs (1, block_n).
+    leading 1 is the batch block; outputs (1, 1, block_n).
     """
     x = x_ref[0].astype(jnp.float32)            # (block_n, d)
     c = c_ref[0].astype(jnp.float32)            # (k, d)
     c2 = c2_ref[0]                              # (1, k) — +inf on pad rows
     x2 = jnp.sum(x * x, axis=1, keepdims=True)  # (block_n, 1)
-    # MXU: (block_n, d) @ (d, k)
+    # MXU: (block_n, d) @ (d, k), in full f32: at the default precision
+    # the near-tie argmins flip and the Lloyd loop never converges
     xc = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        x, c, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     d2 = x2 - 2.0 * xc + c2                     # (block_n, k)
-    labels_ref[0, :] = jnp.argmin(d2, axis=1).astype(jnp.int32)
-    mind2_ref[0, :] = jnp.maximum(jnp.min(d2, axis=1), 0.0)
+    labels_ref[0, 0, :] = jnp.argmin(d2, axis=1).astype(jnp.int32)
+    mind2_ref[0, 0, :] = jnp.maximum(jnp.min(d2, axis=1), 0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -71,7 +73,10 @@ def kmeans_assign_padded(x: jax.Array, c: jax.Array, c2: jax.Array,
         compiling for TPU.
 
     Returns:
-      ``(labels (B, n) int32, min_d2 (B, n) float32)``.
+      ``(labels (B, 1, n) int32, min_d2 (B, 1, n) float32)``. The unit
+      second-minor axis keeps each ``(1, 1, block_n)`` output block equal
+      to the array in its last-but-one dimension, which the TPU lowering
+      requires once ``B > 1``.
     """
     b, n, d = x.shape
     k = c.shape[1]
@@ -86,12 +91,12 @@ def kmeans_assign_padded(x: jax.Array, c: jax.Array, c2: jax.Array,
             pl.BlockSpec((1, 1, k), lambda b, i: (b, 0, 0)),        # |c|^2 row
         ],
         out_specs=[
-            pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
-            pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
+            pl.BlockSpec((1, 1, block_n), lambda b, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_n), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, n), jnp.int32),
-            jax.ShapeDtypeStruct((b, n), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, n), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, n), jnp.float32),
         ],
         interpret=interpret,
     )(x, c, c2)

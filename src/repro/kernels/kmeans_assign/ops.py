@@ -117,8 +117,8 @@ def kmeans_assign(x: jax.Array, centroids: jax.Array
     }
     labels, mind2 = kmeans_assign_padded(x_p, c_p, c2, block_n=block_n,
                                          interpret=interpret)
-    labels = labels[:, :n].reshape(*batch_shape, n)
-    mind2 = mind2[:, :n].reshape(*batch_shape, n)
+    labels = labels[:, 0, :n].reshape(*batch_shape, n)
+    mind2 = mind2[:, 0, :n].reshape(*batch_shape, n)
     return labels, mind2
 
 

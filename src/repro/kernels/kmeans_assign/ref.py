@@ -16,15 +16,15 @@ def kmeans_assign_ref(x: jax.Array, centroids: jax.Array
 
     Returns:
       ``(labels int32 (..., n), min squared distance f32 (..., n))``.
-      Distances computed in f32 with the expanded form
-      |x|^2 - 2 x.cT + |c|^2 (matching the kernel's MXU-friendly
-      formulation).
+      Distances are the direct f32 sum of squared differences — no
+      matmul, so the reference is exact on every backend. (The expanded
+      |x|^2 - 2 x.cT + |c|^2 form with an XLA einsum mislabels about a
+      third of the BBV bank's points on a TPU v5e at the fitted
+      centroids.)
     """
     x = x.astype(jnp.float32)
     c = centroids.astype(jnp.float32)
-    x2 = jnp.sum(x * x, axis=-1, keepdims=True)          # (..., n, 1)
-    c2 = jnp.sum(c * c, axis=-1)                         # (..., k)
-    xc = jnp.einsum("...nd,...kd->...nk", x, c)
-    d2 = x2 - 2.0 * xc + c2[..., None, :]                # (..., n, k)
+    diff = x[..., :, None, :] - c[..., None, :, :]       # (..., n, k, d)
+    d2 = jnp.sum(diff * diff, axis=-1)                   # (..., n, k)
     labels = jnp.argmin(d2, axis=-1).astype(jnp.int32)
-    return labels, jnp.maximum(jnp.min(d2, axis=-1), 0.0)
+    return labels, jnp.min(d2, axis=-1)

@@ -39,14 +39,16 @@ def _segment_kernel(x_ref, lab_ref, sums_ref, sumsq_ref, counts_ref):
     k = sums_ref.shape[1]
     seg_ids = jax.lax.broadcasted_iota(jnp.int32, (BLOCK_N, k), 1)
     onehot = (labels == seg_ids).astype(jnp.float32)   # (BLOCK_N, k)
-    # MXU: (k, BLOCK_N) @ (BLOCK_N, d)
+    # MXU: (k, BLOCK_N) @ (BLOCK_N, d), in full f32 (the f32 contract)
     sums_ref[0] += jax.lax.dot_general(
         onehot, x, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     sumsq_ref[0] += jax.lax.dot_general(
         onehot, x * x, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
-    counts_ref[0] += jnp.sum(onehot, axis=0)
+    counts_ref[0] += jnp.sum(onehot, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "interpret"))
@@ -55,7 +57,10 @@ def segment_stats_padded(x: jax.Array, labels: jax.Array, num_segments: int,
     """x: (b, n, d), n % BLOCK_N == 0; labels: (b, n, 1) int32 (pad = -1).
 
     Returns per-batch-element ``(sums (b, k, d), sumsq (b, k, d),
-    counts (b, k))`` over the ``(batch, n_tiles)`` kernel grid.
+    counts (b, 1, k))`` over the ``(batch, n_tiles)`` kernel grid. The
+    unit second-minor axis of ``counts`` keeps its ``(1, 1, k)`` block
+    equal to the array in its last two dimensions, which the TPU lowering
+    requires once ``b > 1``.
     """
     b, n, d = x.shape
     grid = (b, n // BLOCK_N)
@@ -69,12 +74,12 @@ def segment_stats_padded(x: jax.Array, labels: jax.Array, num_segments: int,
         out_specs=[
             pl.BlockSpec((1, num_segments, d), lambda bi, i: (bi, 0, 0)),
             pl.BlockSpec((1, num_segments, d), lambda bi, i: (bi, 0, 0)),
-            pl.BlockSpec((1, num_segments), lambda bi, i: (bi, 0)),
+            pl.BlockSpec((1, 1, num_segments), lambda bi, i: (bi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, num_segments, d), jnp.float32),
             jax.ShapeDtypeStruct((b, num_segments, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, num_segments), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, num_segments), jnp.float32),
         ],
         interpret=interpret,
     )(x, labels)

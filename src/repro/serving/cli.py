@@ -22,6 +22,7 @@ from ..core.sampling.plan import (Centroid, DaleniusGurney, RFVClusters,
                                   RandomUnit, SamplingPlan)
 from ..experiments.engine import ExperimentEngine
 from ..experiments.sweep import SweepSpec
+from ..runtime.compile_cache import enable_compile_cache
 from .service import SweepService
 
 __all__ = ["main", "synthetic_stream"]
@@ -71,6 +72,8 @@ def main(argv: Sequence[str] | None = None) -> None:
     if args.quick:
         args.requests = min(args.requests, 12)
         args.batch = min(args.batch, 6)
+
+    enable_compile_cache()
 
     service = SweepService(ExperimentEngine.auto(),
                            memo_cap=args.memo_cap,

@@ -248,6 +248,9 @@ def _rfv_bank_fn(x: jnp.ndarray, cv: jnp.ndarray
 
 
 _cpi_bank_jit = jax.jit(_cpi_bank_fn)
+# region-axis tile of cpi_bank dispatches (a multiple of any host's
+# vector width and of the TPU's 128 lanes)
+_REGION_TILE = 128
 _rfv_bank_jit = jax.jit(_rfv_bank_fn)
 
 
@@ -269,9 +272,15 @@ def cpi_bank(features, cfgs, *, mesh=None) -> np.ndarray:
     results identical to the single-device path.
     """
     x = jnp.asarray(features, jnp.float32)
+    n = x.shape[1]
+    # pad the region axis to whole tiles so every region is computed by
+    # the same code in every batch: on a CPU host the ragged tail of a
+    # row came out with other last bits, so a memo fill's CPI depended
+    # on which apps and picks shared its dispatch
+    x = jnp.pad(x, ((0, 0), (0, -n % _REGION_TILE), (0, 0)), mode="edge")
     cm = _as_config_matrix(cfgs)
     fn = _cpi_bank_jit if mesh is None else _sharded(_cpi_bank_fn, mesh)
-    return np.asarray(fn(x, cm))
+    return np.asarray(fn(x, cm))[:, :, :n]
 
 
 def rfv_bank(features, cfg: UarchConfig, *, mesh=None

@@ -142,6 +142,42 @@ def test_pallas_fallback_warns_once_with_reason():
     assert again == resolved
 
 
+def test_kernel_import_failure_raises_on_tpu(monkeypatch):
+    """Off-TPU a broken kernel import degrades to the oracle (warning
+    once); on TPU it raises, so the device path never hides behind it."""
+    from repro.kernels import backend
+
+    def broken():
+        raise ImportError("kernel package missing")
+
+    _reset_backend_warnings()
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: "tpu")
+    for requested in ("pallas", "auto"):
+        with pytest.raises(ImportError, match="kernel package missing"):
+            backend.resolve_backend(requested, kernel="probe",
+                                    import_probe=broken)
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: "cpu")
+    with pytest.warns(BackendFallbackWarning, match="import of the probe"):
+        resolved = backend.resolve_backend("pallas", kernel="probe",
+                                           import_probe=broken)
+    assert resolved.active == "jnp"
+
+
+def test_kmeans_auto_backend_is_kernel_on_tpu_oracle_elsewhere(monkeypatch):
+    """k-means defaults to ``"auto"``: the compiled kernel on a TPU, the
+    jnp oracle (with a one-time warning) anywhere else — so CPU fits keep
+    their bits and chip fits never take the oracle's einsum."""
+    from repro.kernels import backend
+
+    _reset_backend_warnings()
+    with pytest.warns(BackendFallbackWarning, match="has no TPU"):
+        assert resolve_backend("auto").active == "jnp"
+    x = RNG.normal(size=(80, 4)).astype(np.float32)
+    assert kmeans(x, 3, seed=0).backend == "jnp"
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: "tpu")
+    assert resolve_backend("auto").active == "pallas"
+
+
 def test_jnp_backend_never_warns_and_is_recorded():
     _reset_backend_warnings()
     x = RNG.normal(size=(80, 4)).astype(np.float32)

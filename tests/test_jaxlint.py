@@ -16,6 +16,8 @@ import json
 import pathlib
 import textwrap
 
+import pytest
+
 from repro.analysis import main, run_lint
 from repro.analysis.registry import RULES
 
@@ -86,6 +88,23 @@ def test_jl001_print_in_transitively_traced_callee(tmp_path):
         def f(x):
             return helper(x)
     """, select=["JL001"])
+    assert rules_of(r) == ["JL001"]
+
+
+_BODY = "def body(x):\n    return x.item()\n"
+
+
+@pytest.mark.parametrize("site", [
+    _BODY + "run = jax.shard_map(body, mesh=m, in_specs=s, out_specs=s)\n",
+    _BODY + "run = shard_map(body, mesh=m, in_specs=s, out_specs=s)\n",
+    "@functools.partial(jax.shard_map, mesh=m, in_specs=s, out_specs=s)\n"
+    + _BODY,
+])
+def test_jl001_in_function_passed_to_shard_map(tmp_path, site):
+    """The installed JAX spells it ``jax.shard_map``; each spelling of
+    the call the repo uses is a trace site."""
+    header = "import functools\n\nimport jax\nfrom jax import shard_map\n\n"
+    r = lint(tmp_path, header + site, select=["JL001"])
     assert rules_of(r) == ["JL001"]
 
 

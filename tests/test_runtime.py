@@ -1,5 +1,7 @@
 """Checkpoint / elastic / health runtime tests."""
 
+import os
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -171,6 +173,30 @@ def test_trial_stats_checkpoint_roundtrip(tmp_path):
         g, w = np.asarray(g), np.asarray(w)
         assert g.dtype == w.dtype and g.shape == w.shape
         assert g.tobytes() == w.tobytes()
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` wins untouched; otherwise the cache
+    goes to the same fixed directory of the checkout on every call.
+    Importing the library never turns the cache on."""
+    import pathlib
+
+    from repro.runtime import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    assert before == (os.environ.get(compile_cache.CACHE_ENV) or None)
+    monkeypatch.setenv(compile_cache.CACHE_ENV, str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv(compile_cache.CACHE_ENV)
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.enable_compile_cache() == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert pathlib.Path(path) == root / ".jax_cache"
 
 
 def test_elastic_mesh_plans():

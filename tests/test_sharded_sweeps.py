@@ -318,3 +318,54 @@ def test_sharded_engine_matches_single_host():
     for e1, e2 in zip(single.build(APPS2), sharded.build(APPS2)):
         assert e1.sim.ledger.regions_simulated == \
             e2.sim.ledger.regions_simulated
+
+
+# ------------------------------------------------ mesh paths, always run
+_MESH_CHILD = r"""
+import numpy as np
+from repro.core.sampling import Centroid, RFVClusters, SamplingPlan
+from repro.experiments import (ExperimentEngine, SweepSpec, TrialSpec,
+                               run_sweep, run_trials)
+from repro.launch.mesh import make_app_mesh, make_app_trial_mesh
+
+apps = ("505.mcf_r", "520.omnetpp_r")
+single, sharded = ExperimentEngine(), ExperimentEngine(mesh=make_app_mesh())
+spec = SweepSpec(apps=apps, plan=SamplingPlan(RFVClusters(), Centroid()))
+t1, t4 = run_sweep(single, spec), run_sweep(sharded, spec)
+np.testing.assert_array_equal(t1.column("estimate"), t4.column("estimate"))
+assert single.memo.total_charges() == sharded.memo.total_charges()
+# the trial mesh on the one-device engine's bank: the CPU perf model's
+# census bits depend on how many apps share its dispatch
+tspec = TrialSpec(trials=512, schemes=("random", "rfv"), keep_trials=True)
+r1 = run_trials(single, tspec, apps=apps)
+r4 = run_trials(single, tspec, apps=apps,
+                mesh=make_app_trial_mesh(app_devices=2))
+for s in tspec.schemes:
+    for leaf in ("count", "cover", "half_n", "err_hist", "half_hist"):
+        np.testing.assert_array_equal(getattr(r1.stats[s], leaf),
+                                      getattr(r4.stats[s], leaf))
+    for field in ("estimates", "errors", "half_widths"):
+        np.testing.assert_array_equal(getattr(r1, field)[s],
+                                      getattr(r4, field)[s])
+print("mesh paths ok")
+"""
+
+
+def test_mesh_paths_on_four_cpu_devices():
+    """The ("app",) sweep and ("app", "trial") trial paths against one
+    device, in a child with four CPU devices — so a JAX API break in the
+    mesh code fails tier-1 instead of skipping with the 8-device tests.
+    The child stays on the CPU and never loads the TPU library."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   p for p in ("src", os.environ.get("PYTHONPATH")) if p))
+    out = subprocess.run([sys.executable, "-c", _MESH_CHILD],
+                         capture_output=True, text=True, timeout=600,
+                         env=env)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "mesh paths ok" in out.stdout

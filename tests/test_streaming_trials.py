@@ -63,6 +63,37 @@ def test_chunked_equals_unchunked_bitwise(engine):
         np.testing.assert_allclose(st1.err_sum, st2.err_sum, rtol=1e-5)
 
 
+@pytest.mark.parametrize("draws", [2, 20, 1000])
+def test_sum_draws_is_left_to_right_at_any_length(draws):
+    """The trial chunks' draw sum adds left to right (numpy's sequential
+    ``add.accumulate``, bit for bit) with a program whose size does not
+    grow with the draw count."""
+    from repro.experiments.montecarlo import _sum_draws
+
+    x = np.random.default_rng(draws).lognormal(size=(3, 5, draws)).astype(
+        np.float32)
+    np.testing.assert_array_equal(np.asarray(_sum_draws(x)),
+                                  np.add.accumulate(x, axis=-1)[..., -1])
+    eqns = len(jax.make_jaxpr(_sum_draws)(x).jaxpr.eqns)
+    assert eqns == len(jax.make_jaxpr(_sum_draws)(x[..., :2]).jaxpr.eqns)
+
+
+def test_many_units_per_trial_chunked_equals_unchunked(engine):
+    """A realistic SRS sample size (10^3 units per trial) streams, and
+    any chunking still gives bitwise-equal per-trial outcomes."""
+    spec = TrialSpec(trials=512, schemes=("random",), units_per_trial=1000,
+                     keep_trials=True)
+    res1 = run_trials(engine, spec, apps=(APP,))
+    res2 = run_trials(engine, dataclasses.replace(
+        spec, chunk_size=TRIAL_BLOCK), apps=(APP,))
+    for field in ("estimates", "errors", "half_widths"):
+        np.testing.assert_array_equal(getattr(res1, field)["random"],
+                                      getattr(res2, field)["random"])
+    np.testing.assert_array_equal(res1.stats["random"].cover,
+                                  res2.stats["random"].cover)
+    assert 0.85 <= float(res1.coverage["random"][0]) <= 1.0
+
+
 def test_trial_uniforms_matches_block_contract(engine):
     """The dense reference helper reproduces the exact draws the chunked
     scan consumes — trial t at offset t % TRIAL_BLOCK of block
@@ -218,6 +249,21 @@ def test_precision_policy_contract():
     assert len({PrecisionPolicy(), PrecisionPolicy.default()}) == 1
     assert resolve_precision(None, None) == PrecisionPolicy()
     assert resolve_precision(None, pp) is pp
+
+
+def test_x64_context_keeps_float64():
+    """An f64 policy's context computes in float64 on the installed JAX
+    (the one place a JAX upgrade that moves ``enable_x64`` fails)."""
+    import jax.numpy as jnp
+
+    tiny = 2.0 ** -40                  # lost to rounding in float32
+    with PrecisionPolicy(trace="float64").x64_context():
+        x = jnp.asarray(1.0 + tiny, jnp.float64)
+        assert x.dtype == jnp.float64
+        assert float(x - 1.0) == tiny
+    assert jnp.asarray(1.0).dtype == jnp.float32
+    with PrecisionPolicy().x64_context():
+        assert jnp.asarray(1.0).dtype == jnp.float32
 
 
 def test_trials_under_x64_policy_agree_with_f32(engine):

@@ -1,15 +1,20 @@
 """End-to-end behaviour tests for the paper's system."""
 
+import os
 import subprocess
 import sys
 
 
 def test_quickstart_example_runs():
+    # the child stays on the CPU: it never loads the TPU library, whose
+    # lock a compile test of this suite may hold; and the suite writes
+    # no persistent compile cache
     out = subprocess.run(
         [sys.executable, "examples/quickstart.py"],
         capture_output=True, text=True, timeout=900,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"})
+             "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu",
+             "JAX_ENABLE_COMPILATION_CACHE": "false"})
     assert out.returncode == 0, out.stderr[-2000:]
     assert "phase-1" in out.stdout
     assert "covers truth: True" in out.stdout
