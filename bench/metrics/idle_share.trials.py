@@ -1,0 +1,13 @@
+"""idle_share.trials: percent of the window in which the device ran no
+operation (mean over the devices traced)."""
+
+from bench.trace import reduce
+
+
+def read(ctx):
+    ev = ctx.get("trace")
+    win = ev and reduce.span(ev, "window")
+    if not win:
+        return None
+    share = reduce.idle_share(ev, *win)
+    return None if share is None else 100.0 * share
