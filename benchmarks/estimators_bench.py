@@ -295,28 +295,3 @@ def bench_fused_sweep(quick: bool = False) -> dict:
             "max_rel_err": max(r["max_rel_err"] for r in rows),
             "ledger_eq": all(r["ledger_eq"] for r in rows),
             "devices": len(jax.devices())}
-
-
-def profile_fused_sweep(out_dir: str = "profile_traces") -> str:
-    """Dump a ``jax.profiler`` trace of ONE warm fused sweep dispatch
-    (for inspecting that the pipeline really is a single device program).
-    Returns the trace directory."""
-    import jax
-
-    from repro.core.sampling import SamplingPlan
-    from repro.experiments import SweepSpec, run_sweep
-
-    from .simcpu_common import all_apps, get_engine
-
-    engine = get_engine()
-    apps = tuple(all_apps()[:2])
-    engine.build(apps)
-    spec = SweepSpec(apps=apps,
-                     plan=SamplingPlan.from_strings("rfv", "centroid"),
-                     config_indices=(0, 1))
-    run_sweep(engine, spec)                           # compile + fill
-    with jax.profiler.trace(out_dir):
-        run_sweep(engine, spec)
-    print(f"fused_sweep_profile,{out_dir},jax.profiler trace of one "
-          "warm fused sweep")
-    return out_dir

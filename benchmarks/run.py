@@ -129,9 +129,6 @@ def main() -> None:
     ap.add_argument("--devices", type=int, default=None,
                     help="force N XLA host devices (app-sharded sweeps); "
                     "must be set before jax initializes")
-    ap.add_argument("--profile", action="store_true",
-                    help="dump a jax.profiler trace of one warm fused "
-                    "sweep dispatch (dispatch-count inspection)")
     ap.add_argument("--trials", type=int, default=None,
                     help="largest Monte-Carlo trial count for the "
                     "streaming trials bench (default 100000, or 10000 "
@@ -203,14 +200,6 @@ def main() -> None:
             bench_records[name] = {"seconds": round(time.time() - tb, 3),
                                    "error": f"{type(e).__name__}: {e}"}
             errors.append(name)
-
-    if args.profile:
-        print("# === fused sweep profiler trace ===", flush=True)
-        try:
-            estimators_bench.profile_fused_sweep()
-        except Exception as e:  # noqa: BLE001
-            print(f"fused_sweep_profile,ERROR,{type(e).__name__}: {e}")
-            errors.append("fused_sweep_profile")
 
     # ------------------------------------------------ claim validation
     print("# === claim validation (paper vs reproduction) ===")

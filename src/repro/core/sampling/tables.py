@@ -814,27 +814,32 @@ def trial_stats_update(stats: TrialStats, err, half, covered,
     the bitwise half of the chunked == unchunked contract).
     """
     xp = _ns(stats.count, err)
-    v = xp.broadcast_to(xp.asarray(valid, bool), err.shape)
     acc = stats.err_sum.dtype
-    err_ok = v & xp.isfinite(err)
-    half_ok = v & xp.isfinite(half)
 
     def moments(x, m):
         xc = xp.where(m, x, 0).astype(acc)
         return xc.sum(axis=-1), (xc * xc).sum(axis=-1)
 
-    err_s, err_ss = moments(err, err_ok)
-    half_s, half_ss = moments(half, half_ok)
-    return TrialStats(
-        count=stats.count + v.sum(axis=-1).astype(np.int32),
-        cover=stats.cover + (v & covered).sum(axis=-1).astype(np.int32),
-        err_sum=stats.err_sum + err_s,
-        err_sumsq=stats.err_sumsq + err_ss,
-        half_n=stats.half_n + half_ok.sum(axis=-1).astype(np.int32),
-        half_sum=stats.half_sum + half_s,
-        half_sumsq=stats.half_sumsq + half_ss,
-        err_hist=_hist_add(stats.err_hist, err, err_ok, xp),
-        half_hist=_hist_add(stats.half_hist, half, half_ok, xp))
+    # named scopes label the device time of a traced trial scan
+    with jax.named_scope("trials.fold"):
+        v = xp.broadcast_to(xp.asarray(valid, bool), err.shape)
+        err_ok = v & xp.isfinite(err)
+        half_ok = v & xp.isfinite(half)
+        err_s, err_ss = moments(err, err_ok)
+        half_s, half_ss = moments(half, half_ok)
+        folded = dict(
+            count=stats.count + v.sum(axis=-1).astype(np.int32),
+            cover=stats.cover + (v & covered).sum(axis=-1).astype(np.int32),
+            err_sum=stats.err_sum + err_s,
+            err_sumsq=stats.err_sumsq + err_ss,
+            half_n=stats.half_n + half_ok.sum(axis=-1).astype(np.int32),
+            half_sum=stats.half_sum + half_s,
+            half_sumsq=stats.half_sumsq + half_ss)
+    with jax.named_scope("trials.hist"):
+        return TrialStats(
+            **folded,
+            err_hist=_hist_add(stats.err_hist, err, err_ok, xp),
+            half_hist=_hist_add(stats.half_hist, half, half_ok, xp))
 
 
 def trial_stats_merge(a: TrialStats, b: TrialStats) -> TrialStats:

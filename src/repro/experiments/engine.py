@@ -313,66 +313,78 @@ class ExperimentEngine:
 
     # ------------------------------------------------------------------ build
     def _build_stacked(self, names: tuple[str, ...], kmeans_seed: int) -> None:
+        """Build the apps ``names`` as one stack; profiler spans
+        ``build.population``, ``build.census``, ``build.bbv``,
+        ``build.phase1``, ``build.rfv`` and ``build.dg`` mark its
+        stages."""
         from ..simcpu import get_bbvs
 
         L = self.num_strata
         mesh = self.mesh
-        bank = get_population_bank(names)
-        a_n = bank.num_apps
-        ar = np.arange(a_n)
+        with jax.profiler.TraceAnnotation("build.population"):
+            bank = get_population_bank(names)
+            a_n = bank.num_apps
+            ar = np.arange(a_n)
 
-        sims = []
-        for name, pop in zip(names, bank.pops):
-            base = make_simulator(name)
-            row = self.memo.add_app(name, pop.n_regions, base.ledger)
-            sims.append(CachedSimulator(base, bank=self.memo, row=row))
+            sims = []
+            for name, pop in zip(names, bank.pops):
+                base = make_simulator(name)
+                row = self.memo.add_app(name, pop.n_regions, base.ledger)
+                sims.append(CachedSimulator(base, bank=self.memo, row=row))
 
         # census ground truth for every config: one vmapped program
         # (analysis-only — free of charge, bypasses the charged memo)
-        census = cpi_bank(bank.features, config_matrix(self.configs),
-                          mesh=mesh)                       # (A, C, N)
-        truth = np.where(bank.mask[:, None, :], census, 0.0).sum(
-            axis=2, dtype=np.float64) / bank.n_regions[:, None]
+        with jax.profiler.TraceAnnotation("build.census"):
+            census = cpi_bank(bank.features, config_matrix(self.configs),
+                              mesh=mesh)                   # (A, C, N)
+            truth = np.where(bank.mask[:, None, :], census, 0.0).sum(
+                axis=2, dtype=np.float64) / bank.n_regions[:, None]
 
         # SimPoint-style BBV stratification over the full populations
-        bbvs, _ = stack_ragged([get_bbvs(p) for p in bank.pops],
-                               dtype=np.float32)
-        z = np.asarray(_project_bank(bbvs, mesh=mesh))     # (A, N, 15)
-        bbv_fit = kmeans_bank(z, L, weights=bank.mask.astype(np.float32),
-                              seed=kmeans_seed, mesh=mesh)
-        bbv_counts = _offset_bincount(bbv_fit.labels, bank.mask, L)
-        bbv_w = bbv_counts / bank.n_regions[:, None]
+        with jax.profiler.TraceAnnotation("build.bbv"):
+            bbvs, _ = stack_ragged([get_bbvs(p) for p in bank.pops],
+                                   dtype=np.float32)
+            z = np.asarray(_project_bank(bbvs, mesh=mesh))  # (A, N, 15)
+            bbv_fit = kmeans_bank(z, L, weights=bank.mask.astype(np.float32),
+                                  seed=kmeans_seed, mesh=mesh)
+            bbv_counts = _offset_bincount(bbv_fit.labels, bank.mask, L)
+            bbv_w = bbv_counts / bank.n_regions[:, None]
 
         # phase 1: SRS at the paper's Table II sizes, measured on config 0
         # as ONE stacked dispatch, charged through the shared memo bank
-        idx1_list = [draw_srs(np.random.default_rng(self.phase1_seed),
-                              pop.n_regions, pop.spec.phase1_n)
-                     for pop in bank.pops]
-        idx1, idx1_valid = stack_ragged(idx1_list)
-        cpi0, rfv = rfv_bank(bank.features[ar[:, None], idx1],
-                             self.configs[0], mesh=mesh)
-        rows = np.asarray([s.row for s in sims], np.int64)
-        self.memo.fill(rows, idx1, idx1_valid, (self.configs[0],),
-                       values=cpi0[:, None, :])
+        with jax.profiler.TraceAnnotation("build.phase1"):
+            idx1_list = [draw_srs(np.random.default_rng(self.phase1_seed),
+                                  pop.n_regions, pop.spec.phase1_n)
+                         for pop in bank.pops]
+            idx1, idx1_valid = stack_ragged(idx1_list)
+            cpi0, rfv = rfv_bank(bank.features[ar[:, None], idx1],
+                                 self.configs[0], mesh=mesh)
+            rows = np.asarray([s.row for s in sims], np.int64)
+            self.memo.fill(rows, idx1, idx1_valid, (self.configs[0],),
+                           values=cpi0[:, None, :])
 
         # RFV stratification: masked batched z-scoring + weighted k-means
-        n1 = idx1_valid.sum(axis=1)                        # (A,)
-        v3 = idx1_valid[:, :, None]
-        mean = np.where(v3, rfv, 0.0).sum(1) / n1[:, None]
-        var = np.where(v3, (rfv - mean[:, None, :]) ** 2, 0.0).sum(1) \
-            / n1[:, None]
-        scale = np.sqrt(var)
-        scale = np.where(scale > 1e-12, scale, 1.0)
-        zr = np.where(v3, (rfv - mean[:, None, :]) / scale[:, None, :], 0.0)
-        rfv_fit = kmeans_bank(zr, L, weights=idx1_valid.astype(np.float32),
-                              seed=kmeans_seed, mesh=mesh)
-        rfv_w = _offset_bincount(rfv_fit.labels, idx1_valid, L) / n1[:, None]
+        with jax.profiler.TraceAnnotation("build.rfv"):
+            n1 = idx1_valid.sum(axis=1)                    # (A,)
+            v3 = idx1_valid[:, :, None]
+            mean = np.where(v3, rfv, 0.0).sum(1) / n1[:, None]
+            var = np.where(v3, (rfv - mean[:, None, :]) ** 2, 0.0).sum(1) \
+                / n1[:, None]
+            scale = np.sqrt(var)
+            scale = np.where(scale > 1e-12, scale, 1.0)
+            zr = np.where(v3, (rfv - mean[:, None, :]) / scale[:, None, :],
+                          0.0)
+            rfv_fit = kmeans_bank(zr, L, weights=idx1_valid.astype(np.float32),
+                                  seed=kmeans_seed, mesh=mesh)
+            rfv_w = _offset_bincount(rfv_fit.labels, idx1_valid, L) \
+                / n1[:, None]
 
         # Dalenius-Gurney on baseline CPI (host-side scalar refinement)
-        dg_list = [dalenius_gurney_strata(cpi0[a, :n1[a]], L)
-                   for a in range(a_n)]
-        dg, _ = stack_ragged(dg_list)
-        dg_w = _offset_bincount(dg, idx1_valid, L) / n1[:, None]
+        with jax.profiler.TraceAnnotation("build.dg"):
+            dg_list = [dalenius_gurney_strata(cpi0[a, :n1[a]], L)
+                       for a in range(a_n)]
+            dg, _ = stack_ragged(dg_list)
+            dg_w = _offset_bincount(dg, idx1_valid, L) / n1[:, None]
 
         for a, (name, sim, pop) in enumerate(zip(names, sims, bank.pops)):
             n, n1_a = pop.n_regions, int(n1[a])

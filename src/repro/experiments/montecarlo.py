@@ -245,15 +245,17 @@ def _srs_chunk(u, truth, crit, pool, n_valid):
     """(A, Tc, n) uniforms x (A, N) value pool -> per-trial estimate,
     percent error, eq. (2) t-interval half-width and CI-covers-truth."""
     n = u.shape[2]
-    idx = jnp.minimum((u * n_valid[:, None, None]).astype(jnp.int32),
-                      (n_valid - 1)[:, None, None].astype(jnp.int32))
-    vals = _gather_lanes(pool, idx)
-    est = _sum_draws(vals) / n
-    err = 100.0 * jnp.abs(est - truth[:, None]) / truth[:, None]
-    ss = _sum_draws((vals - est[:, :, None]) ** 2)
-    v_mean = jnp.where(n > 1, ss / max(n - 1, 1), jnp.nan) / n
-    half = crit[:, None] * jnp.sqrt(v_mean)
-    covered = jnp.abs(est - truth[:, None]) <= half
+    with jax.named_scope("trials.select"):
+        idx = jnp.minimum((u * n_valid[:, None, None]).astype(jnp.int32),
+                          (n_valid - 1)[:, None, None].astype(jnp.int32))
+        vals = _gather_lanes(pool, idx)
+        est = _sum_draws(vals) / n
+        err = 100.0 * jnp.abs(est - truth[:, None]) / truth[:, None]
+    with jax.named_scope("trials.ci"):
+        ss = _sum_draws((vals - est[:, :, None]) ** 2)
+        v_mean = jnp.where(n > 1, ss / max(n - 1, 1), jnp.nan) / n
+        half = crit[:, None] * jnp.sqrt(v_mean)
+        covered = jnp.abs(est - truth[:, None]) <= half
     return est, err, half, covered
 
 
@@ -265,24 +267,26 @@ def _stratified_chunk(u, truth, crit, sorted_vals, offsets, counts,
     lane-wise by ``sampling_tables.collapsed_pairs_grouped`` from the
     app's host-computed ``collapsed_pairs_groups`` (wsq, in_grp, has3)."""
     a, t, l = u.shape
-    pick = offsets[:, None, :] + jnp.minimum(
-        (u * counts[:, None, :]).astype(jnp.int32),
-        jnp.maximum(counts - 1, 0)[:, None, :].astype(jnp.int32))
-    # trailing empty strata put offsets at the row width: clamp explicitly
-    # (the pick is zero-weighted via `occupied` below)
-    pick = jnp.minimum(pick, sorted_vals.shape[1] - 1)
-    vals = _gather_lanes(sorted_vals, pick)
-    occupied = (counts > 0)[:, None, :]
-    est = _sum_draws(vals * weights[:, None, :] * occupied)
-    err = 100.0 * jnp.abs(est - truth[:, None]) / truth[:, None]
-    # collapsed-pairs CI: stratum draws gathered into key order
-    y_sorted = jnp.take_along_axis(
-        vals, jnp.broadcast_to(key_order[:, None, :], (a, t, l)), axis=2)
-    var, _ = sampling_tables.collapsed_pairs_grouped(
-        y_sorted, tuple(g[:, None, :] for g in (wsq, in_grp, has3)),
-        n_occ[:, None])
-    half = crit[:, None] * jnp.sqrt(var)
-    covered = jnp.abs(est - truth[:, None]) <= half
+    with jax.named_scope("trials.select"):
+        pick = offsets[:, None, :] + jnp.minimum(
+            (u * counts[:, None, :]).astype(jnp.int32),
+            jnp.maximum(counts - 1, 0)[:, None, :].astype(jnp.int32))
+        # trailing empty strata put offsets at the row width: clamp
+        # explicitly (the pick is zero-weighted via `occupied` below)
+        pick = jnp.minimum(pick, sorted_vals.shape[1] - 1)
+        vals = _gather_lanes(sorted_vals, pick)
+        occupied = (counts > 0)[:, None, :]
+        est = _sum_draws(vals * weights[:, None, :] * occupied)
+        err = 100.0 * jnp.abs(est - truth[:, None]) / truth[:, None]
+    with jax.named_scope("trials.ci"):
+        # collapsed-pairs CI: stratum draws gathered into key order
+        y_sorted = jnp.take_along_axis(
+            vals, jnp.broadcast_to(key_order[:, None, :], (a, t, l)), axis=2)
+        var, _ = sampling_tables.collapsed_pairs_grouped(
+            y_sorted, tuple(g[:, None, :] for g in (wsq, in_grp, has3)),
+            n_occ[:, None])
+        half = crit[:, None] * jnp.sqrt(var)
+        covered = jnp.abs(est - truth[:, None]) <= half
     return est, err, half, covered
 
 
@@ -331,10 +335,12 @@ def _streaming_program(chunk_fn, mesh, *, kb: int, n_chunks: int,
             (app_ids.shape[0],), accum_dtype=np.dtype(accum), xp=jnp)
 
         def step(carry, c):
-            b0 = (chunk0 + c) * kb + ti * kbd
-            u = _run_uniforms(key, b0, kbd, app_ids, draws, dt)
+            with jax.named_scope("trials.draw"):
+                b0 = (chunk0 + c) * kb + ti * kbd
+                u = _run_uniforms(key, b0, kbd, app_ids, draws, dt)
             est, err, half, covered = chunk_fn(u, truth, crit, *tables)
-            valid = (b0 * TRIAL_BLOCK + jnp.arange(tc)) < trials
+            with jax.named_scope("trials.fold"):
+                valid = (b0 * TRIAL_BLOCK + jnp.arange(tc)) < trials
             carry = sampling_tables.trial_stats_update(
                 carry, err, half, covered, valid[None, :])
             return carry, ((est, err, half) if keep else None)
@@ -426,6 +432,7 @@ def charged_pool_fill(engine: ExperimentEngine, spec: TrialSpec, apps,
     return cpi[:, 0, :]
 
 
+@functools.partial(jax.profiler.annotate_function, name="trials.setup")
 def _scheme_setup(engine: ExperimentEngine, spec: TrialSpec, apps, mesh,
                   stratifiers: Optional[dict] = None):
     """Resolve everything a scheme's chunk program consumes on the host.
@@ -441,74 +448,81 @@ def _scheme_setup(engine: ExperimentEngine, spec: TrialSpec, apps, mesh,
     memo fills here are the trial path's ONLY charged work (re-running
     them after a restore is a pure cache hit, keeping ledger totals
     path-independent).
+
+    Profiler spans: ``trials.setup`` around the whole, ``trials.resolve``
+    (the build's banks and the census pool), ``trials.pool_fill`` and,
+    per scheme, ``trials.tables``.
     """
-    exps = engine.build(apps)
-    stack = engine.stack(apps)
     ci = spec.config_index
-    cfg = engine.configs[ci]
     l_n = engine.num_strata
     pp = resolve_precision(spec.precision, engine.precision)
     tdt = pp.trace_dtype
-    truth = np.stack([e.truth[ci] for e in exps])
+    with jax.profiler.TraceAnnotation("trials.resolve"):
+        exps = engine.build(apps)
+        stack = engine.stack(apps)
+        truth = np.stack([e.truth[ci] for e in exps])
 
-    # registry-resolved stratifications: each scheme name becomes a
-    # Stratifier whose StratumBank declares its labels, weights and
-    # order key — and whose ``pool_kind`` declares the value-pool cost
-    # semantics — no per-scheme branches below
-    strats = {s: (stratifiers or {}).get(s)
-              or sampling_plan.make_stratifier(s)
-              for s in spec.schemes if s != SRS_DRAWS}
-    banks = {s: strat.resolve(exps) for s, strat in strats.items()}
-    charged = {s for s, strat in strats.items()
-               if strat.pool_kind == "phase1"}
+        # registry-resolved stratifications: each scheme name becomes a
+        # Stratifier whose StratumBank declares its labels, weights and
+        # order key — and whose ``pool_kind`` declares the value-pool
+        # cost semantics — no per-scheme branches below
+        strats = {s: (stratifiers or {}).get(s)
+                  or sampling_plan.make_stratifier(s)
+                  for s in spec.schemes if s != SRS_DRAWS}
+        banks = {s: strat.resolve(exps) for s, strat in strats.items()}
+        charged = {s for s, strat in strats.items()
+                   if strat.pool_kind == "phase1"}
+        # census CPI value pool (free)
+        census, _ = stack_ragged([e.census(ci) for e in exps], dtype=tdt)
 
-    # value pools: census CPI (free) and phase-1 CPI (charged once, via
-    # the serving-shared helper so request dedup can replay the hit)
-    census, _ = stack_ragged([e.census(ci) for e in exps], dtype=tdt)
-    p1_pool = charged_pool_fill(engine, spec, apps, mesh, stratifiers)
+    # phase-1 CPI value pool (charged once, via the serving-shared helper
+    # so request dedup can replay the hit)
+    with jax.profiler.TraceAnnotation("trials.pool_fill"):
+        p1_pool = charged_pool_fill(engine, spec, apps, mesh, stratifiers)
     if p1_pool is not None:
         p1_pool = p1_pool.astype(tdt)                      # (A, n1_max)
 
     setups: dict[str, tuple] = {}
     for scheme in spec.schemes:
-        if scheme == SRS_DRAWS:
-            n = spec.units_per_trial
-            dfs = np.full(len(apps), float(n - 1) if n < 30 else np.inf)
+        with jax.profiler.TraceAnnotation("trials.tables", scheme=scheme):
+            if scheme == SRS_DRAWS:
+                n = spec.units_per_trial
+                dfs = np.full(len(apps), float(n - 1) if n < 30 else np.inf)
+                crit = critical_values(spec.confidence, dfs).astype(tdt)
+                setups[scheme] = (_srs_chunk, n, crit,
+                                  (census, stack.n_regions))
+                continue
+            bank = banks[scheme]
+            labels, lv = bank.labels, bank.valid
+            weights = bank.weights
+            if scheme in charged:                 # phase-1 pool, paid once
+                pool = p1_pool
+            elif bank.pool is None:               # census-indexed labels
+                pool = census
+            else:                                 # census values at pool idx
+                pool = np.take_along_axis(census, bank.pool, axis=1)
+            baseline = bank.baseline.astype(tdt)
+            # ONE stratum-summary dispatch serves the collapsed-pairs
+            # ordering key AND the gather-table counts
+            key, countsf = _stratum_key_counts(baseline, labels, lv, l_n,
+                                               precision=pp)
+            order, offsets, counts = stratum_tables(labels, lv, l_n,
+                                                    counts=countsf)
+            sorted_vals = np.take_along_axis(pool, order, axis=1)
+            # collapsed-pairs CI geometry: occupied strata first, in
+            # baseline-CPI key order (static per app)
+            key_order = np.argsort(key, axis=1, kind="stable")
+            w_sorted = np.take_along_axis(weights, key_order, axis=1)
+            n_occ = (counts > 0).sum(axis=1)
+            groups = sampling_tables.collapsed_pairs_groups(
+                w_sorted.astype(tdt), n_occ, num_strata=l_n)
+            dfs = np.maximum(n_occ - n_occ // 2, 1).astype(np.float64)
             crit = critical_values(spec.confidence, dfs).astype(tdt)
-            setups[scheme] = (_srs_chunk, n, crit,
-                              (census, stack.n_regions))
-            continue
-        bank = banks[scheme]
-        labels, lv = bank.labels, bank.valid
-        weights = bank.weights
-        if scheme in charged:                 # phase-1 pool, paid once
-            pool = p1_pool
-        elif bank.pool is None:               # census-indexed labels
-            pool = census
-        else:                                 # census values at pool idx
-            pool = np.take_along_axis(census, bank.pool, axis=1)
-        baseline = bank.baseline.astype(tdt)
-        # ONE stratum-summary dispatch serves the collapsed-pairs
-        # ordering key AND the gather-table counts
-        key, countsf = _stratum_key_counts(baseline, labels, lv, l_n,
-                                           precision=pp)
-        order, offsets, counts = stratum_tables(labels, lv, l_n,
-                                                counts=countsf)
-        sorted_vals = np.take_along_axis(pool, order, axis=1)
-        # collapsed-pairs CI geometry: occupied strata first, in
-        # baseline-CPI key order (static per app)
-        key_order = np.argsort(key, axis=1, kind="stable")
-        w_sorted = np.take_along_axis(weights, key_order, axis=1)
-        n_occ = (counts > 0).sum(axis=1)
-        groups = sampling_tables.collapsed_pairs_groups(
-            w_sorted.astype(tdt), n_occ, num_strata=l_n)
-        dfs = np.maximum(n_occ - n_occ // 2, 1).astype(np.float64)
-        crit = critical_values(spec.confidence, dfs).astype(tdt)
-        setups[scheme] = (_stratified_chunk, l_n, crit,
-                          (sorted_vals, offsets.astype(np.int32),
-                           counts.astype(np.int32), weights.astype(tdt),
-                           key_order.astype(np.int32)) + groups
-                          + (n_occ.astype(np.int32),))
+            setups[scheme] = (_stratified_chunk, l_n, crit,
+                              (sorted_vals, offsets.astype(np.int32),
+                               counts.astype(np.int32), weights.astype(tdt),
+                               key_order.astype(np.int32)) + groups
+                              + (n_occ.astype(np.int32),))
     return truth, pp, setups
 
 
@@ -531,43 +545,54 @@ def run_trials(engine: ExperimentEngine, spec: TrialSpec = TrialSpec(),
     used; unmapped schemes are built from the registry with defaults.
     """
     apps = tuple(apps or APP_NAMES)
-    mesh = engine.mesh if mesh is None else mesh
-    if mesh is None:
-        ntd = 1
-    else:
-        from ..distributed.appaxis import app_trial_axes
-        _, trial_axis = app_trial_axes(mesh)
-        ntd = 1 if trial_axis is None else mesh.shape[trial_axis]
-    kb, n_chunks = _chunk_blocks(spec, ntd)
-    keep = (spec.keep_trials if spec.keep_trials is not None
-            else spec.trials <= _KEEP_TRIALS_MAX)
-    app_ids = np.arange(len(apps), dtype=np.int32)
-    truth, pp, setups = _scheme_setup(engine, spec, apps, mesh, stratifiers)
-    tdt = pp.trace_dtype
+    # profiler spans: ``trials.run`` around the study, then per scheme
+    # ``trials.dispatch`` (with the bytes of the host arrays it uploads)
+    # and ``trials.fetch`` (its results copied to the host)
+    with jax.profiler.TraceAnnotation("trials.run", seed=spec.seed,
+                                      trials=spec.trials):
+        mesh = engine.mesh if mesh is None else mesh
+        if mesh is None:
+            ntd = 1
+        else:
+            from ..distributed.appaxis import app_trial_axes
+            _, trial_axis = app_trial_axes(mesh)
+            ntd = 1 if trial_axis is None else mesh.shape[trial_axis]
+        kb, n_chunks = _chunk_blocks(spec, ntd)
+        keep = (spec.keep_trials if spec.keep_trials is not None
+                else spec.trials <= _KEEP_TRIALS_MAX)
+        app_ids = np.arange(len(apps), dtype=np.int32)
+        truth, pp, setups = _scheme_setup(engine, spec, apps, mesh,
+                                          stratifiers)
+        tdt = pp.trace_dtype
 
-    stats: dict[str, sampling_tables.TrialStats] = {}
-    estimates: dict[str, np.ndarray] = {}
-    errors: dict[str, np.ndarray] = {}
-    halves: dict[str, np.ndarray] = {}
-    for scheme in spec.schemes:
-        chunk_fn, draws, crit, tables = setups[scheme]
-        program = _streaming_program(
-            chunk_fn, mesh, kb=kb, n_chunks=n_chunks, trials=spec.trials,
-            draws=draws, trace=pp.trace, accum=pp.accum, keep=keep)
-        with pp.x64_context():
-            st, ys = program(trial_key(spec, scheme), np.int32(0), app_ids,
-                             truth.astype(tdt), crit, *tables)
-            if mesh is None:
-                st, ys = _trim_streaming_out((st, ys), len(apps))
-        stats[scheme] = jax.tree.map(np.asarray, st)
-        if keep:
-            # (n_chunks, A, chunk) stacks -> (A, T) trial-major views
-            est, err, half = (
-                np.asarray(y).transpose(1, 0, 2).reshape(len(apps), -1)
-                [:, :spec.trials] for y in ys)
-            estimates[scheme] = est
-            errors[scheme] = err
-            halves[scheme] = half
-    return TrialResult(apps=apps, spec=spec, stats=stats,
-                       estimates=estimates, errors=errors,
-                       half_widths=halves)
+        stats: dict[str, sampling_tables.TrialStats] = {}
+        estimates: dict[str, np.ndarray] = {}
+        errors: dict[str, np.ndarray] = {}
+        halves: dict[str, np.ndarray] = {}
+        for scheme in spec.schemes:
+            chunk_fn, draws, crit, tables = setups[scheme]
+            program = _streaming_program(
+                chunk_fn, mesh, kb=kb, n_chunks=n_chunks, trials=spec.trials,
+                draws=draws, trace=pp.trace, accum=pp.accum, keep=keep)
+            with pp.x64_context():
+                host = (app_ids, truth.astype(tdt), crit, *tables)
+                h2d = sum(a.nbytes for a in host if isinstance(a, np.ndarray))
+                with jax.profiler.TraceAnnotation(
+                        "trials.dispatch", scheme=scheme, h2d_bytes=h2d):
+                    st, ys = program(trial_key(spec, scheme), np.int32(0),
+                                     *host)
+                    if mesh is None:
+                        st, ys = _trim_streaming_out((st, ys), len(apps))
+            with jax.profiler.TraceAnnotation("trials.fetch", scheme=scheme):
+                stats[scheme] = jax.tree.map(np.asarray, st)
+                if keep:
+                    # (n_chunks, A, chunk) stacks -> (A, T) trial-major views
+                    est, err, half = (
+                        np.asarray(y).transpose(1, 0, 2).reshape(len(apps), -1)
+                        [:, :spec.trials] for y in ys)
+                    estimates[scheme] = est
+                    errors[scheme] = err
+                    halves[scheme] = half
+        return TrialResult(apps=apps, spec=spec, stats=stats,
+                           estimates=estimates, errors=errors,
+                           half_widths=halves)
