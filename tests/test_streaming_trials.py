@@ -78,6 +78,72 @@ def test_sum_draws_is_left_to_right_at_any_length(draws):
     assert eqns == len(jax.make_jaxpr(_sum_draws)(x[..., :2]).jaxpr.eqns)
 
 
+def _key_order_gather(vals, key_order):
+    """The collapsed-pairs key order as a gather: the reference that the
+    key-order select must equal bit for bit."""
+    import jax.numpy as jnp
+
+    return jnp.take_along_axis(
+        vals, jnp.broadcast_to(key_order[:, None, :], vals.shape), axis=2)
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.dtype(f"u{x.dtype.itemsize}"))
+
+
+@pytest.mark.parametrize("num_strata", [1, 2, 3, 20, 50])
+def test_key_order_select_equals_gather_bitwise(num_strata):
+    """Each trial's stratum draws reach key order unchanged bit for bit,
+    with trailing empty strata (key +inf, count 0) ordered last and
+    values a gather passes through untouched: signed zeros, infinities,
+    subnormals, a NaN's payload. The select lowers to no gather."""
+    from repro.experiments.montecarlo import _key_order_select
+
+    rng = np.random.default_rng(num_strata)
+    a, t = 4, 9
+    counts = rng.integers(1, 50, (a, num_strata))
+    for i in range(a):                       # app i: i trailing empties
+        counts[i, max(num_strata - i, 1):] = 0
+    key = np.where(counts > 0, rng.random((a, num_strata)), np.inf)
+    key_order = np.argsort(key, axis=1, kind="stable").astype(np.int32)
+    vals = rng.lognormal(size=(a, t, num_strata)).astype(np.float32)
+    special = np.array([-0.0, 0.0, np.inf, -np.inf, 1e-45, -3.5,
+                        np.uint32(0x7FC00123).view(np.float32)], np.float32)
+    vals.reshape(-1)[:special.size] = special[:vals.size]
+
+    got = _key_order_select(vals, key_order)
+    np.testing.assert_array_equal(_bits(got),
+                                  _bits(_key_order_gather(vals, key_order)))
+    lowered = jax.jit(_key_order_select).lower(vals, key_order).as_text()
+    assert "gather" not in lowered
+
+
+def test_run_trials_equals_key_order_gather_bitwise(engine, monkeypatch):
+    """A whole study with the key-order select gives the per-trial
+    estimates, errors, half-widths and every ``TrialStats`` leaf of the
+    same study with the key-order gather, bit for bit."""
+    from repro.experiments import montecarlo
+
+    spec = TrialSpec(trials=600, keep_trials=True)       # every scheme
+    montecarlo._streaming_program.cache_clear()
+    new = run_trials(engine, spec, apps=APPS2)
+    monkeypatch.setattr(montecarlo, "_key_order_select", _key_order_gather)
+    montecarlo._streaming_program.cache_clear()
+    try:
+        old = run_trials(engine, spec, apps=APPS2)
+    finally:
+        montecarlo._streaming_program.cache_clear()
+    for s in spec.schemes:
+        for field in ("estimates", "errors", "half_widths"):
+            np.testing.assert_array_equal(
+                _bits(getattr(new, field)[s]), _bits(getattr(old, field)[s]))
+        for leaf in dataclasses.fields(new.stats[s]):
+            np.testing.assert_array_equal(
+                _bits(getattr(new.stats[s], leaf.name)),
+                _bits(getattr(old.stats[s], leaf.name)), err_msg=leaf.name)
+
+
 def test_many_units_per_trial_chunked_equals_unchunked(engine):
     """A realistic SRS sample size (10^3 units per trial) streams, and
     any chunking still gives bitwise-equal per-trial outcomes."""

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -147,11 +148,21 @@ def test_fused_megaprogram_compiles_at_paper_matrix(spec,
     assert est.shape == (A, C)
 
 
+def _scope_gathers(hlo: str, scope: str) -> list[str]:
+    """The compiled program's gather instructions whose metadata places
+    them under the named scope."""
+    return [line for line in hlo.splitlines()
+            if re.search(r"= [^=]*\bgather\(", line)
+            and re.search(r'op_name="[^"]*\b' + re.escape(scope) + "/", line)]
+
+
 @pytest.mark.parametrize("scheme", ["random", "rfv"])
 def test_trial_scan_fits_one_chip_at_bank_size(spec, scheme):
     """The 10^4-trial scan over the 10-app bank at the default chunk: the
     per-trial gathers must not broadcast a census pool over the trial
-    axis (10 x 4096 x 120k f32 would not fit the chip)."""
+    axis (10 x 4096 x 120k f32 would not fit the chip), and the
+    collapsed-pairs key order stays a select: a v5e runs a gather
+    element by element, and at L = 20 that one took 45% of the scan."""
     from repro.experiments import montecarlo as mc
 
     f32, i32 = jnp.float32, jnp.int32
@@ -173,3 +184,5 @@ def test_trial_scan_fits_one_chip_at_bank_size(spec, scheme):
                           spec((A,), f32), *tables).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < HBM_BYTES // 16
+    assert _scope_gathers(compiled.as_text(), "trials.select")
+    assert not _scope_gathers(compiled.as_text(), "trials.ci")
