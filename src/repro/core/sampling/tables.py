@@ -694,7 +694,8 @@ class TrialStats:
     chunking or sharding of the trial axis accumulates to the same
     totals — bitwise for the integer leaves (trial counts, coverage
     counts, histogram sketches) and up to float summation order for the
-    moment sums. Leading axes (``...``) are batch lanes (apps); per-trial
+    moment sums (the streaming trial program pins that order:
+    ``repro.experiments.montecarlo._block_moments``). Leading axes (``...``) are batch lanes (apps); per-trial
     ``T``-axis arrays never materialize.
 
     ``err_hist``/``half_hist`` are log-spaced histogram sketches over

@@ -113,8 +113,7 @@ def app_sharded_cached(fn: Callable, mesh: Mesh,
 
 def make_app_trial_sharded(fn: Callable, mesh: Mesh,
                            replicated: Sequence[int] = (),
-                           *, out_specs,
-                           trim: "Callable | None" = None) -> Callable:
+                           *, out_specs) -> Callable:
     """``make_app_sharded`` generalized to ``("app", "trial")`` meshes.
 
     Inputs follow the app contract exactly — leading-axis arrays shard
@@ -127,9 +126,9 @@ def make_app_trial_sharded(fn: Callable, mesh: Mesh,
       accumulators (``P(app)``, replicated over the trial axis after the
       in-program ``psum`` merge) next to optional dense chunk stacks
       assembled over both axes (``P(None, app, trial)``).
-    * ``trim(out, a_size)`` drops the app padding, because the app axis
-      is not leading in every output (default: leading-axis slice on
-      every leaf, matching ``make_app_sharded``).
+    * the outputs keep the app padding: the app axis is not leading in
+      every output, and the caller drops the padding where it fetches
+      the outputs to the host.
 
     ``fn`` itself may read ``jax.lax.axis_index`` of either axis to pick
     its shard of the work — see ``repro.experiments.montecarlo``.
@@ -145,13 +144,8 @@ def make_app_trial_sharded(fn: Callable, mesh: Mesh,
                                      out_specs=out_specs, check_vma=False))
 
     def call(*args: Any):
-        a_size = next(np.shape(a)[0] for i, a in enumerate(args)
-                      if i not in rep)
         padded = tuple(a if i in rep else pad_app_axis(a, n_app)
                        for i, a in enumerate(args))
-        out = build(len(args))(*padded)
-        if trim is None:
-            return jax.tree.map(lambda o: o[:a_size], out)
-        return trim(out, a_size)
+        return build(len(args))(*padded)
 
     return call

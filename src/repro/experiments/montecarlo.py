@@ -23,6 +23,10 @@ ped over both axes — app lanes stay independent, and the trial axis
 splits each chunk's blocks across devices, with the accumulator merged
 by a ``psum`` over the trial axis (additivity makes the cross-device
 coverage/CI merge exact: sharded totals equal single-device totals).
+The float moments are summed per PRNG block and then over the blocks in
+an order fixed by the trial count (``_block_moments``,
+``_pairwise_sum``), so they too are bitwise the same at any chunking and
+on any mesh.
 
 The per-trial math is unchanged from the vmapped design: the SRS scheme
 evaluates the eq. (2) t-interval, the one-unit-per-stratum schemes the
@@ -72,7 +76,8 @@ TRIAL_SCHEMES = (SRS_DRAWS, "bbv", "rfv", "dg")
 # the unit the chunked scan, the trial-mesh split and the dense reference
 # all agree on. Chunk sizes are multiples of this.
 TRIAL_BLOCK = 256
-# default trials per scan step: bounds live memory at ~chunk × pool-width
+# default trials per scan step on one device: bounds its live memory at
+# ~chunk × pool-width
 _DEFAULT_CHUNK = 4096
 # keep dense (A, T) per-trial arrays by default up to this many trials
 # (the Fig 8 regime); past it only the streamed statistics come home
@@ -90,8 +95,9 @@ class TrialSpec:
     than mid-study.
 
     Streaming knobs: ``chunk_size`` fixes the trials evaluated per scan
-    step (a positive multiple of ``TRIAL_BLOCK``; default ~4096, rounded
-    to the trial-mesh split) — it changes memory and scheduling, never
+    step (a positive multiple of ``TRIAL_BLOCK``; default ~4096 per
+    device of the mesh, rounded to the trial-mesh split and evened out
+    over the scan) — it changes memory and scheduling, never
     results. ``keep_trials`` forces (True) or suppresses (False) the
     dense per-trial ``(A, T)`` arrays; default keeps them only up to
     8192 trials. ``precision`` overrides the engine's
@@ -259,6 +265,39 @@ def _srs_chunk(u, truth, crit, pool, n_valid):
     return est, err, half, covered
 
 
+def _pairwise_sum(x, width: int):
+    """Sum over the last axis as a tree of elementwise adds over
+    ``width`` entries (a power of two): the axis is cut to ``width`` or
+    padded with zeros to it, then halved ``log2(width)`` times. The order
+    of every add depends on ``width`` alone, so the sum is the same bits
+    in every program that holds the same entries, whatever else it
+    holds; the caller cuts off only zeros."""
+    x = x[..., :width]
+    x = jnp.pad(x, ((0, 0),) * (x.ndim - 1)
+                + ((0, width - x.shape[-1]),))
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x[..., 0]
+
+
+# the float moments of ``TrialStats``, in the order ``_block_moments``
+# stacks them
+_MOMENTS = ("err_sum", "err_sumsq", "half_sum", "half_sumsq")
+
+
+def _block_moments(err, half, valid, accum):
+    """(4, A, Tc // TRIAL_BLOCK) sums of the error, its square, the
+    half-width and its square over each PRNG block's valid, finite
+    trials, in ``_MOMENTS`` order and the accumulator dtype (the values
+    ``trial_stats_update`` sums, summed per block in a fixed order)."""
+    e = jnp.where(valid & jnp.isfinite(err), err, 0).astype(accum)
+    h = jnp.where(valid & jnp.isfinite(half), half, 0).astype(accum)
+    x = jnp.stack([e, e * e, h, h * h])
+    x = x.reshape(x.shape[:2] + (-1, TRIAL_BLOCK))
+    return _pairwise_sum(x, TRIAL_BLOCK)
+
+
 def _key_order_select(vals, key_order):
     """(A, T, L) stratum draws into each app's collapsed-pairs key order:
     ``vals[a, t, key_order[a, p]]`` at ``[a, t, p]``, ``key_order`` an
@@ -330,6 +369,11 @@ def _streaming_program(chunk_fn, mesh, *, kb: int, n_chunks: int,
     multiple of the axis size), each device folds its own blocks into a
     local accumulator, and a final ``psum`` over the trial axis merges
     the totals — additive leaves make the merge exact.
+
+    The float moments do not ride the running sums, whose order of adds
+    would follow the chunking and the split: each block's moments land
+    in a column of their own, the ``psum`` adds only zeros to them, and
+    one ``_pairwise_sum`` over a width fixed by ``trials`` totals them.
     """
     chunk = kb * TRIAL_BLOCK
     dt = jnp.dtype(trace)
@@ -341,28 +385,43 @@ def _streaming_program(chunk_fn, mesh, *, kb: int, n_chunks: int,
         ntd = 1 if trial_axis is None else mesh.shape[trial_axis]
     kbd = kb // ntd                 # blocks per trial-device per chunk
     tc = kbd * TRIAL_BLOCK          # trials per trial-device per chunk
+    # the tree that totals the per-block moments spans every block of
+    # the trial count (a power of two wide), whatever this program holds
+    width = 1 << max(-(-trials // TRIAL_BLOCK) - 1, 0).bit_length()
 
     def prog(key, chunk0, app_ids, truth, crit, *tables):
         ti = (jax.lax.axis_index(trial_axis)
               if trial_axis is not None else 0)
+        a_n = app_ids.shape[0]
         stats0 = sampling_tables.trial_stats_init(
-            (app_ids.shape[0],), accum_dtype=np.dtype(accum), xp=jnp)
+            (a_n,), accum_dtype=np.dtype(accum), xp=jnp)
+        parts0 = jnp.zeros((len(_MOMENTS), a_n, n_chunks * kb),
+                           np.dtype(accum))
 
         def step(carry, c):
+            stats, parts = carry
             with jax.named_scope("trials.draw"):
                 b0 = (chunk0 + c) * kb + ti * kbd
                 u = _run_uniforms(key, b0, kbd, app_ids, draws, dt)
             est, err, half, covered = chunk_fn(u, truth, crit, *tables)
             with jax.named_scope("trials.fold"):
-                valid = (b0 * TRIAL_BLOCK + jnp.arange(tc)) < trials
-            carry = sampling_tables.trial_stats_update(
-                carry, err, half, covered, valid[None, :])
-            return carry, ((est, err, half) if keep else None)
+                valid = ((b0 * TRIAL_BLOCK + jnp.arange(tc)) < trials)[None]
+                parts = jax.lax.dynamic_update_slice(
+                    parts, _block_moments(err, half, valid, np.dtype(accum)),
+                    (0, 0, c * kb + ti * kbd))
+            # its own moment sums are left unread (XLA drops them)
+            stats = sampling_tables.trial_stats_update(
+                stats, err, half, covered, valid)
+            return (stats, parts), ((est, err, half) if keep else None)
 
-        stats, ys = jax.lax.scan(step, stats0, jnp.arange(n_chunks))
+        (stats, parts), ys = jax.lax.scan(step, (stats0, parts0),
+                                          jnp.arange(n_chunks))
         if trial_axis is not None:
-            stats = jax.tree.map(lambda x: jax.lax.psum(x, trial_axis),
-                                 stats)
+            with jax.named_scope("trials.merge"):
+                stats, parts = jax.lax.psum((stats, parts), trial_axis)
+        with jax.named_scope("trials.fold"):
+            totals = _pairwise_sum(parts, width)
+        stats = dataclasses.replace(stats, **dict(zip(_MOMENTS, totals)))
         return stats, ys
 
     if mesh is None:
@@ -372,29 +431,57 @@ def _streaming_program(chunk_fn, mesh, *, kb: int, n_chunks: int,
     from ..distributed.appaxis import app_trial_axes, make_app_trial_sharded
     app_axis, trial_axis = app_trial_axes(mesh)
     ys_spec = (P(None, app_axis, trial_axis),) * 3 if keep else None
+    # the app padding stays on the devices: the host drops it as it
+    # fetches the outputs (``_fetch_streaming_out``)
     return make_app_trial_sharded(
-        prog, mesh, replicated=(0, 1), out_specs=(P(app_axis), ys_spec),
-        trim=_trim_streaming_out)
+        prog, mesh, replicated=(0, 1), out_specs=(P(app_axis), ys_spec))
 
 
-def _trim_streaming_out(out, a_size: int):
-    """Drop app-axis padding: stats lead with the app axis, dense chunk
-    stacks carry it second (``(n_chunks, A, chunk)``)."""
+def _fetch_streaming_out(out, a_size: int):
+    """Host copies of a streaming program's outputs, gathered from every
+    device that holds a shard, without the app-axis padding: stats lead
+    with the app axis, dense chunk stacks carry it second
+    (``(n_chunks, A, chunk)``)."""
     stats, ys = out
-    stats = jax.tree.map(lambda x: x[:a_size], stats)
+    stats = jax.tree.map(lambda x: np.asarray(x)[:a_size], stats)
     if ys is not None:
-        ys = jax.tree.map(lambda y: y[:, :a_size], ys)
+        ys = tuple(np.asarray(y)[:, :a_size] for y in ys)
     return stats, ys
 
 
-def _chunk_blocks(spec: TrialSpec, ntd: int) -> tuple[int, int]:
+def _h2d_bytes(host, mesh) -> int:
+    """Bytes a dispatch sends from the host to the devices: each host
+    array once on one device, or, under ``mesh``, its app axis padded
+    to the app-axis size and each app shard on every device of the
+    trial axis that holds a copy of it."""
+    arrays = [a for a in host if isinstance(a, np.ndarray)]
+    if mesh is None:
+        return sum(a.nbytes for a in arrays)
+    from ..distributed.appaxis import app_trial_axes
+    n_app = int(mesh.shape[app_trial_axes(mesh)[0]])
+    copies = mesh.size // n_app
+    return sum(a.nbytes // max(a.shape[0], 1) * -(-a.shape[0] // n_app)
+               * n_app * copies for a in arrays)
+
+
+def _chunk_blocks(spec: TrialSpec, ntd: int,
+                  devices: int = 1) -> tuple[int, int]:
     """(kb, n_chunks): blocks per chunk — a multiple of the trial-axis
-    size so each device owns whole blocks — and the scan length."""
+    size so each device owns whole blocks — and the scan length.
+
+    The default chunk bounds one device's live memory: over a mesh of
+    ``devices`` each device holds a ``1/devices`` share of a chunk's
+    trial-lanes, so the default chunk is ``devices`` times one device's,
+    evened out over the scan so that the last chunk pads as few blocks
+    as it can. An explicit ``chunk_size`` is taken as given."""
     blocks_needed = -(-spec.trials // TRIAL_BLOCK)
-    kb = -(-(spec.chunk_size or _DEFAULT_CHUNK) // TRIAL_BLOCK)
+    kb = -(-(spec.chunk_size or _DEFAULT_CHUNK * devices) // TRIAL_BLOCK)
     kb = min(kb, blocks_needed)
     kb = -(-kb // ntd) * ntd
     n_chunks = -(-blocks_needed // kb)
+    if spec.chunk_size is None:
+        even = -(-blocks_needed // n_chunks)
+        kb = -(-even // ntd) * ntd
     return kb, n_chunks
 
 
@@ -558,20 +645,25 @@ def run_trials(engine: ExperimentEngine, spec: TrialSpec = TrialSpec(),
     parameterized plug-in studies the same stratification its sweep
     used; unmapped schemes are built from the registry with defaults.
     """
+    from ..launch.mesh import mesh_tag
+
     apps = tuple(apps or APP_NAMES)
-    # profiler spans: ``trials.run`` around the study, then per scheme
-    # ``trials.dispatch`` (with the bytes of the host arrays it uploads)
-    # and ``trials.fetch`` (its results copied to the host)
+    mesh = engine.mesh if mesh is None else mesh
+    # profiler spans: ``trials.run`` around the study (with the mesh's
+    # layout), then per scheme ``trials.dispatch`` (with the bytes it
+    # sends to all devices) and ``trials.fetch`` (its results gathered
+    # to the host)
     with jax.profiler.TraceAnnotation("trials.run", seed=spec.seed,
-                                      trials=spec.trials):
-        mesh = engine.mesh if mesh is None else mesh
+                                      trials=spec.trials,
+                                      mesh=mesh_tag(mesh)):
         if mesh is None:
             ntd = 1
         else:
             from ..distributed.appaxis import app_trial_axes
             _, trial_axis = app_trial_axes(mesh)
             ntd = 1 if trial_axis is None else mesh.shape[trial_axis]
-        kb, n_chunks = _chunk_blocks(spec, ntd)
+        kb, n_chunks = _chunk_blocks(
+            spec, ntd, 1 if mesh is None else mesh.size)
         keep = (spec.keep_trials if spec.keep_trials is not None
                 else spec.trials <= _KEEP_TRIALS_MAX)
         app_ids = np.arange(len(apps), dtype=np.int32)
@@ -590,19 +682,17 @@ def run_trials(engine: ExperimentEngine, spec: TrialSpec = TrialSpec(),
                 draws=draws, trace=pp.trace, accum=pp.accum, keep=keep)
             with pp.x64_context():
                 host = (app_ids, truth.astype(tdt), crit, *tables)
-                h2d = sum(a.nbytes for a in host if isinstance(a, np.ndarray))
                 with jax.profiler.TraceAnnotation(
-                        "trials.dispatch", scheme=scheme, h2d_bytes=h2d):
-                    st, ys = program(trial_key(spec, scheme), np.int32(0),
-                                     *host)
-                    if mesh is None:
-                        st, ys = _trim_streaming_out((st, ys), len(apps))
+                        "trials.dispatch", scheme=scheme,
+                        h2d_bytes=_h2d_bytes(host, mesh)):
+                    out = program(trial_key(spec, scheme), np.int32(0),
+                                  *host)
             with jax.profiler.TraceAnnotation("trials.fetch", scheme=scheme):
-                stats[scheme] = jax.tree.map(np.asarray, st)
+                stats[scheme], ys = _fetch_streaming_out(out, len(apps))
                 if keep:
                     # (n_chunks, A, chunk) stacks -> (A, T) trial-major views
                     est, err, half = (
-                        np.asarray(y).transpose(1, 0, 2).reshape(len(apps), -1)
+                        y.transpose(1, 0, 2).reshape(len(apps), -1)
                         [:, :spec.trials] for y in ys)
                     estimates[scheme] = est
                     errors[scheme] = err

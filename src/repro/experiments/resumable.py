@@ -68,8 +68,8 @@ from ..runtime.health import QuantumHealth
 from ..simcpu import APP_NAMES
 from .engine import ExperimentEngine
 from .montecarlo import (_KEEP_TRIALS_MAX, TRIAL_BLOCK, TrialResult,
-                         TrialSpec, _chunk_blocks, _scheme_setup,
-                         _streaming_program, _trim_streaming_out, trial_key)
+                         TrialSpec, _chunk_blocks, _fetch_streaming_out,
+                         _scheme_setup, _streaming_program, trial_key)
 from .sweep import ResultsTable, SweepRow, SweepSpec, run_sweep
 
 __all__ = ["FleetReport", "run_sweep_resumable", "run_trials_resumable",
@@ -295,16 +295,14 @@ def run_trials_resumable(engine: ExperimentEngine,
             chunk_fn, prog_mesh, kb=kb, n_chunks=nc, trials=spec.trials,
             draws=draws, trace=pp.trace, accum=pp.accum, keep=keep_dense)
         with pp.x64_context():
-            st, ys = program(trial_key(spec, scheme), np.int32(c0),
-                             app_ids, truth.astype(tdt), crit, *tables)
-            if prog_mesh is None:
-                st, ys = _trim_streaming_out((st, ys), a_n)
-        st = jax.tree.map(np.asarray, st)
+            out = program(trial_key(spec, scheme), np.int32(c0),
+                          app_ids, truth.astype(tdt), crit, *tables)
+        st, ys = _fetch_streaming_out(out, a_n)
         stats[scheme] = sampling_tables.trial_stats_merge(stats[scheme], st)
         if keep_dense:
             off = c0 * kb * TRIAL_BLOCK
             for name, y in zip(("est", "err", "half"), ys):
-                arr = np.asarray(y).transpose(1, 0, 2).reshape(a_n, -1)
+                arr = y.transpose(1, 0, 2).reshape(a_n, -1)
                 dense[scheme][name][:, off:off + arr.shape[1]] = arr
         if injector is not None:
             injector.quantum_computed()

@@ -76,6 +76,15 @@ def make_app_trial_mesh(app_devices: int = 1,
     return Mesh(grid, ("app", "trial"))
 
 
+def mesh_tag(mesh: Optional[Mesh]) -> str:
+    """A mesh's layout as one word, axis names and sizes in order:
+    ``"app2xtrial2"`` for a 2 x 2 ``("app", "trial")`` mesh, ``"none"``
+    for no mesh (one device)."""
+    if mesh is None:
+        return "none"
+    return "x".join(f"{name}{size}" for name, size in mesh.shape.items())
+
+
 def make_host_mesh(model_parallel: int = 1) -> Mesh:
     """Small mesh over the actually-available devices (tests/examples)."""
     n = len(jax.devices())

@@ -254,9 +254,32 @@ _REGION_TILE = 128
 _rfv_bank_jit = jax.jit(_rfv_bank_fn)
 
 
+# apps per dispatch, and per device of an app mesh, at the least: XLA's
+# CPU backend folds away an app axis of one and compiles other code for
+# it, whose CPI came out with other last bits than the same app's in a
+# dispatch over several, so a census depended on how the apps were
+# split over devices
+_MIN_APPS = 2
+
+
 def _sharded(fn, mesh):
     from ..distributed.appaxis import app_sharded_cached
     return app_sharded_cached(fn, mesh, (1,))
+
+
+def _pin_apps(x: jnp.ndarray, mesh) -> jnp.ndarray:
+    """``x`` with its app axis padded by edge replication, so that the
+    dispatch, or each device's share of it under ``mesh``, holds at least
+    ``_MIN_APPS`` apps: the same code then computes every app's rows
+    whatever the app count and the mesh (callers drop the padding)."""
+    n_dev = 1
+    if mesh is not None:
+        from ..distributed.appaxis import app_trial_axes
+        n_dev = int(mesh.shape[app_trial_axes(mesh)[0]])
+    pad = _MIN_APPS * n_dev - x.shape[0]
+    if pad <= 0:
+        return x
+    return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1), mode="edge")
 
 
 def _as_config_matrix(cfgs) -> jnp.ndarray:
@@ -268,29 +291,32 @@ def cpi_bank(features, cfgs, *, mesh=None) -> np.ndarray:
 
     ``features``: (A, N, F) stacked (possibly padded) app feature arrays;
     ``cfgs``: a config sequence or a prebuilt (C, 14) matrix. With ``mesh``
-    (a 1-D ``("app",)`` mesh) the app axis runs device-parallel with
-    results identical to the single-device path.
+    (a 1-D ``("app",)`` mesh, or the app axis of an ``("app", "trial")``
+    mesh) the app axis runs device-parallel with results bitwise equal to
+    the single-device path (``_pin_apps``).
     """
     x = jnp.asarray(features, jnp.float32)
-    n = x.shape[1]
+    a, n = x.shape[:2]
     # pad the region axis to whole tiles so every region is computed by
     # the same code in every batch: on a CPU host the ragged tail of a
     # row came out with other last bits, so a memo fill's CPI depended
     # on which apps and picks shared its dispatch
-    x = jnp.pad(x, ((0, 0), (0, -n % _REGION_TILE), (0, 0)), mode="edge")
+    x = jnp.pad(_pin_apps(x, mesh),
+                ((0, 0), (0, -n % _REGION_TILE), (0, 0)), mode="edge")
     cm = _as_config_matrix(cfgs)
     fn = _cpi_bank_jit if mesh is None else _sharded(_cpi_bank_fn, mesh)
-    return np.asarray(fn(x, cm))[:, :, :n]
+    return np.asarray(fn(x, cm))[:a, :, :n]
 
 
 def rfv_bank(features, cfg: UarchConfig, *, mesh=None
              ) -> tuple[np.ndarray, np.ndarray]:
     """Stacked phase-1 measurement: (A, N) CPI + (A, N, 38) RFV matrix."""
     x = jnp.asarray(features, jnp.float32)
+    a = x.shape[0]
     cv = _config_vector(cfg)
     fn = _rfv_bank_jit if mesh is None else _sharded(_rfv_bank_fn, mesh)
-    cpi, rfv = fn(x, cv)
-    return np.asarray(cpi), np.asarray(rfv)
+    cpi, rfv = fn(_pin_apps(x, mesh), cv)
+    return np.asarray(cpi)[:a], np.asarray(rfv)[:a]
 
 
 def stats_matrix(stats: Mapping[str, np.ndarray]) -> np.ndarray:

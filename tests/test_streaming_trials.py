@@ -59,8 +59,11 @@ def test_chunked_equals_unchunked_bitwise(engine):
         np.testing.assert_array_equal(st1.cover, st2.cover)
         np.testing.assert_array_equal(st1.err_hist, st2.err_hist)
         np.testing.assert_array_equal(st1.half_hist, st2.half_hist)
-        # float moment sums only differ by summation order across chunks
-        np.testing.assert_allclose(st1.err_sum, st2.err_sum, rtol=1e-5)
+        # the float moments sum per PRNG block, then over the blocks in
+        # an order fixed by the trial count, not by the chunking
+        for leaf in ("err_sum", "err_sumsq", "half_sum", "half_sumsq"):
+            np.testing.assert_array_equal(_bits(getattr(st1, leaf)),
+                                          _bits(getattr(st2, leaf)))
 
 
 @pytest.mark.parametrize("draws", [2, 20, 1000])
@@ -177,6 +180,29 @@ def test_chunk_size_must_align_to_block():
         TrialSpec(chunk_size=100)
 
 
+@pytest.mark.parametrize("trials", [1_000, 10_000, 100_000, 1_000_000])
+@pytest.mark.parametrize("ntd,devices", [(1, 1), (1, 2), (2, 4), (4, 8)])
+def test_default_chunk_is_one_devices_per_device(trials, ntd, devices):
+    """The default geometry covers the trials with whole blocks per
+    trial device, holds per device at most one device's default
+    trial-lanes (app shards of 10 apps), and pads less than a chunk
+    per trial device; an explicit chunk size is kept."""
+    from repro.experiments.montecarlo import _DEFAULT_CHUNK, _chunk_blocks
+
+    blocks = -(-trials // TRIAL_BLOCK)
+    kb, n_chunks = _chunk_blocks(TrialSpec(trials=trials), ntd, devices)
+    assert kb % ntd == 0 and kb * n_chunks >= blocks
+    assert kb * n_chunks - blocks < n_chunks * ntd
+    apps_per_device = -(-10 // (devices // ntd))
+    assert (kb // ntd * TRIAL_BLOCK * apps_per_device
+            <= _DEFAULT_CHUNK * 10 + ntd * TRIAL_BLOCK * apps_per_device)
+    if devices == 1:
+        assert n_chunks == -(-blocks // (_DEFAULT_CHUNK // TRIAL_BLOCK))
+    spec = TrialSpec(trials=trials, chunk_size=2048)
+    assert _chunk_blocks(spec, ntd, devices)[0] == -(
+        -min(8, blocks) // ntd) * ntd
+
+
 # ------------------------------------------------ streamed vs dense parity
 def test_streamed_stats_match_dense_reductions(engine):
     """TrialStats totals agree with dense per-trial reductions: counts
@@ -266,9 +292,9 @@ def test_100k_trials_stream_with_calibrated_coverage(engine):
 # ------------------------------------------------ sharded (app x trial)
 @needs_devices
 def test_app_trial_mesh_totals_match_single_device(engine):
-    """(app x trial) sharded totals == single-device: integer leaves
-    bitwise, dense per-trial arrays bitwise (the same PRNG blocks are
-    evaluated, merely on different devices), moments to rounding."""
+    """(app x trial) sharded totals == single-device: integer leaves,
+    float moments and dense per-trial arrays all bitwise (the same PRNG
+    blocks are evaluated, merely on different devices)."""
     from repro.launch.mesh import make_app_trial_mesh
 
     spec = TrialSpec(trials=1000, keep_trials=True)
@@ -281,7 +307,7 @@ def test_app_trial_mesh_totals_match_single_device(engine):
         np.testing.assert_array_equal(st1.count, st2.count)
         np.testing.assert_array_equal(st1.cover, st2.cover)
         np.testing.assert_array_equal(st1.err_hist, st2.err_hist)
-        np.testing.assert_allclose(st1.err_sum, st2.err_sum, rtol=1e-5)
+        np.testing.assert_array_equal(_bits(st1.err_sum), _bits(st2.err_sum))
         np.testing.assert_array_equal(single.estimates[s],
                                       sharded.estimates[s])
         np.testing.assert_array_equal(single.half_widths[s],
@@ -298,6 +324,65 @@ def test_trial_axis_splits_chunks():
     app_axis, trial_axis = app_trial_axes(mesh)
     assert (app_axis, trial_axis) == ("app", "trial")
     assert mesh.shape["app"] == 2 and mesh.shape["trial"] == 4
+
+
+_MESH_ENGINE_CHILD = r"""
+import dataclasses
+
+import numpy as np
+
+from repro.experiments import ExperimentEngine, TrialSpec, run_trials
+from repro.launch.mesh import make_app_trial_mesh
+
+def bits(x):
+    return np.ascontiguousarray(x).view(np.uint8)
+
+apps = ("505.mcf_r", "520.omnetpp_r")
+mesh = make_app_trial_mesh(app_devices=2)
+assert dict(mesh.shape) == {"app": 2, "trial": 2}, mesh.shape
+one, sharded = ExperimentEngine(), ExperimentEngine(mesh=mesh)
+for e1, e4 in zip(one.build(apps), sharded.build(apps)):
+    for f in ("truth", "census_mat", "idx1", "cpi0_1", "bbv_feats",
+              "bbv_labels", "bbv_centroids", "rfv_z", "rfv_labels",
+              "rfv_centroids", "dg_labels"):
+        np.testing.assert_array_equal(bits(getattr(e1, f)),
+                                      bits(getattr(e4, f)), err_msg=f)
+spec = TrialSpec(trials=1000, keep_trials=True)
+r1, r4 = run_trials(one, spec, apps=apps), run_trials(sharded, spec, apps=apps)
+for s in spec.schemes:
+    for leaf in dataclasses.fields(r1.stats[s]):
+        np.testing.assert_array_equal(
+            bits(getattr(r1.stats[s], leaf.name)),
+            bits(getattr(r4.stats[s], leaf.name)), err_msg=f"{s} {leaf.name}")
+    for field in ("estimates", "half_widths"):
+        np.testing.assert_array_equal(bits(getattr(r1, field)[s]),
+                                      bits(getattr(r4, field)[s]),
+                                      err_msg=f"{s} {field}")
+print("mesh engine ok")
+"""
+
+
+def test_app_trial_mesh_engine_on_four_cpu_devices():
+    """An engine built on a 2 x 2 ``("app", "trial")`` mesh against a
+    one-device engine, in a child with four CPU devices: the census
+    truth, the census pool, the phase-1 sample, the features, z-scores,
+    centroids and labels of the strata, then every ``TrialStats`` leaf,
+    the estimates and the half-widths of a study, all bit for bit. One
+    app per device of the app axis, the split at which the perf model's
+    bits once depended on the mesh."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   p for p in ("src", os.environ.get("PYTHONPATH")) if p))
+    out = subprocess.run([sys.executable, "-c", _MESH_ENGINE_CHILD],
+                         capture_output=True, text=True, timeout=600,
+                         env=env)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "mesh engine ok" in out.stdout
 
 
 # ------------------------------------------------ precision policy

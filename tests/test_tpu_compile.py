@@ -5,8 +5,10 @@ lowering's tiling and VMEM rules, so these tests compile — without a
 chip — the two Pallas kernels, the build's k-means fit, the fused sweep
 megaprogram and the trial scan at the paper bank's real shapes (10
 apps, up to 120k regions, 7 configs, L=20) for one chip of a ``v5e:2x2``
-topology. Nothing runs; a refusal by the
-TPU compiler fails the test.
+topology, and the app-sharded build programs and the trial scan with
+its ``psum`` merge for the whole ``v5e:2x2`` as a 2 x 2 ``("app",
+"trial")`` mesh. Nothing runs; a refusal by the TPU compiler fails the
+test.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and every
@@ -24,7 +26,7 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.core.features import RFV_METRICS
 from repro.core.precision import PrecisionPolicy
@@ -186,3 +188,83 @@ def test_trial_scan_fits_one_chip_at_bank_size(spec, scheme):
     assert mem.temp_size_in_bytes < HBM_BYTES // 16
     assert _scope_gathers(compiled.as_text(), "trials.select")
     assert not _scope_gathers(compiled.as_text(), "trials.ci")
+
+
+# ------------------------------------------------ the 2 x 2 (app, trial) mesh
+@pytest.fixture(scope="module")
+def mesh2x2(topo):
+    from repro.launch.mesh import make_app_trial_mesh
+
+    return make_app_trial_mesh(app_devices=2, devices=topo.devices)
+
+
+@pytest.fixture
+def on_mesh(mesh2x2):
+    """``ShapeDtypeStruct`` factory laid out on the described mesh: app
+    shards (``"app"``) or replicated (``None``)."""
+    def make(shape, dtype, axis="app"):
+        spec = PartitionSpec(axis) if axis else PartitionSpec()
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh2x2, spec))
+    return make
+
+
+@pytest.mark.parametrize("program", ["kmeans_fit", "cpi_bank"])
+def test_app_sharded_build_compiles_on_2x2(on_mesh, mesh2x2, monkeypatch,
+                                           program):
+    """The build's BBV fit, with the compiled assignment kernel, and the
+    census over the 10-app bank, each shard_map-ped over the app axis
+    of the four chips (the trial axis holds copies)."""
+    from repro.core.clustering.kmeans import _bank_fit_fn
+    from repro.distributed.appaxis import make_app_sharded
+    from repro.simcpu import perfmodel
+
+    f32 = jnp.float32
+    if program == "kmeans_fit":
+        monkeypatch.setattr(kmeans_ops, "_on_tpu", lambda: True)
+        fn = make_app_sharded(_bank_fit_fn(L, 100, "pallas", 1e-8), mesh2x2,
+                              (0,))
+        args = (on_mesh((2,), jnp.uint32, None),
+                on_mesh((A, N_MAX, 15), f32), on_mesh((A, N_MAX), f32))
+    else:
+        fn = make_app_sharded(perfmodel._cpi_bank_fn, mesh2x2, (1,))
+        args = (on_mesh((A, N_ROWS, NUM_FEATURES), f32),
+                on_mesh((C, NUM_CONFIG_FIELDS), f32, None))
+    compiled = jax.jit(fn).lower(*args).compile()
+    if program == "kmeans_fit":
+        rec = kmeans_ops.last_dispatch()
+        assert rec["interpret"] is False and rec["grid"][0] == A // 2
+        assert _has_kernel(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES // 4
+
+
+@pytest.mark.parametrize("scheme", ["random", "rfv"])
+def test_trial_scan_compiles_on_2x2_with_its_merge(on_mesh, mesh2x2, scheme):
+    """The 10^5-trial scan over the 10-app bank on the four chips: each
+    chip scans its app shard's half of every chunk, and the statistics
+    meet in all-reduces under the ``trials.merge`` scope."""
+    from repro.experiments import montecarlo as mc
+
+    f32, i32 = jnp.float32, jnp.int32
+    if scheme == "random":
+        chunk_fn, draws = mc._srs_chunk, 20
+        tables = (on_mesh((A, N_MAX), f32), on_mesh((A,), i32))
+    else:
+        chunk_fn, draws = mc._stratified_chunk, L
+        tables = (on_mesh((A, N1_MAX), f32),) + tuple(
+            on_mesh((A, L), dt) for dt in (i32, i32, f32, i32)) + tuple(
+            on_mesh((A, L // 2), dt) for dt in (f32, bool, bool)) + (
+            on_mesh((A,), i32),)
+    kb, n_chunks = mc._chunk_blocks(mc.TrialSpec(trials=100_000), 2, 4)
+    prog = mc._streaming_program(
+        chunk_fn, mesh2x2, kb=kb, n_chunks=n_chunks, trials=100_000,
+        draws=draws, trace="float32", accum="float32", keep=False)
+    compiled = jax.jit(prog).lower(
+        on_mesh((2,), jnp.uint32, None), on_mesh((), i32, None),
+        on_mesh((A,), i32), on_mesh((A,), f32), on_mesh((A,), f32),
+        *tables).compile()
+    hlo = compiled.as_text()
+    merges = [line for line in hlo.splitlines()
+              if re.search(r"= [^=]*\ball-reduce\(", line)]
+    assert merges and all("trials.merge" in line for line in merges)
+    assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES // 16

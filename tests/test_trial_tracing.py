@@ -72,7 +72,8 @@ def test_run_trials_spans_nest_under_run(traced):
     runs = [s for s in spans if s["name"] == "trials.run"]
     assert len(runs) == 1
     run = runs[0]
-    assert run["args"] == {"seed": spec.seed, "trials": spec.trials}
+    assert run["args"] == {"seed": spec.seed, "trials": spec.trials,
+                           "mesh": "none"}
     names = {s["name"] for s in spans}
     assert names >= {"trials.setup", "trials.resolve", "trials.pool_fill",
                      "trials.tables", "trials.dispatch", "trials.fetch"}
@@ -91,6 +92,35 @@ def test_per_scheme_spans_name_their_scheme(traced, name):
     assert [s["args"]["scheme"] for s in per] == list(spec.schemes)
     if name == "trials.dispatch":
         assert all(s["args"]["h2d_bytes"] > 0 for s in per)
+
+
+def _mesh_2x2():
+    """What ``mesh_tag`` and ``_h2d_bytes`` read of a 2 x 2
+    ``("app", "trial")`` mesh, without four devices."""
+    from types import SimpleNamespace
+
+    return SimpleNamespace(axis_names=("app", "trial"),
+                           shape={"app": 2, "trial": 2}, size=4)
+
+
+def test_mesh_tag_names_the_layout():
+    from repro.launch.mesh import mesh_tag
+
+    assert mesh_tag(None) == "none"
+    assert mesh_tag(_mesh_2x2()) == "app2xtrial2"
+
+
+def test_h2d_bytes_count_every_devices_copy():
+    """Under a 2 x 2 mesh each app shard goes to both devices of its
+    trial axis, the app axis padded to whole shards first."""
+    from repro.experiments.montecarlo import _h2d_bytes
+
+    ten = (np.zeros(10, np.int32), np.zeros((10, 6), np.float32),
+           np.int32(0))
+    assert _h2d_bytes(ten, None) == 40 + 240
+    assert _h2d_bytes(ten, _mesh_2x2()) == 2 * (40 + 240)
+    three = (np.zeros((3, 5), np.float32),)
+    assert _h2d_bytes(three, _mesh_2x2()) == 2 * 4 * 5 * 4
 
 
 def test_build_stage_spans_in_order_inside_resolve(traced):
