@@ -9,11 +9,15 @@
 ``bench/configs/spec17int_k20.json``) with ``ExperimentEngine`` on one
 layout, one process per layout: ``none`` on the first device, or
 ``app<a>xtrial<t>`` on a ``make_app_trial_mesh(app_devices=a)`` over the
-first ``a * t`` devices. It then runs ``run_trials`` at fixed study seeds
-and writes a sha256 of each build output and of each statistic of each
-study, and a digest of each study (its statistics in the order of
-``bench/drivers/trials.py``'s digest). ``compare`` lists what differs
-between two such files and exits non-zero if anything does.
+first ``a * t`` devices. It then runs ``run_trials`` at fixed study seeds,
+for each trial count of ``--trials`` in turn, on one engine, and writes
+a sha256 of each build output and of each statistic of each study (and
+of its dense per-trial arrays, which a study keeps up to 8192 trials),
+a digest of each study (its statistics in the order of
+``bench/drivers/trials.py``'s digest), and the engine's study-memo hit
+and miss counts where the program has them. ``compare`` lists what
+differs between two such files (two layouts, or two trees on one
+layout) and exits non-zero if anything does.
 
 On a TPU the k-means kernels must run compiled: a fallback to the
 oracle is an error. The persistent compile cache is on, as in a
@@ -84,30 +88,42 @@ def run(args) -> dict:
         out["build_s"] = time.perf_counter() - t0
         for f in BUILD_FIELDS:
             out["build"][f] = sha(*(getattr(e, f) for e in exps))
-        for seed in args.seeds:
-            spec = TrialSpec(trials=args.trials, keep_trials=False,
-                             seed=seed)
-            t0 = time.perf_counter()
-            res = run_trials(engine, spec, apps=apps)
-            study = {"seed": seed, "s": time.perf_counter() - t0,
-                     "stats": {}}
-            digest = hashlib.sha256()
-            for sch in sorted(res.stats):
-                st = vars(res.stats[sch])
-                for k in sorted(st):
-                    study["stats"][f"{sch}.{k}"] = sha(st[k])
-                    digest.update(np.ascontiguousarray(st[k]).tobytes())
-            study["digest"] = digest.hexdigest()
-            out["studies"].append(study)
-            print(f"{out['layout']} seed {seed}: {study['s']:.3f} s, "
-                  f"digest {study['digest']}", flush=True)
+        for trials in args.trials:
+            for seed in args.seeds:
+                spec = TrialSpec(trials=trials, seed=seed)
+                t0 = time.perf_counter()
+                res = run_trials(engine, spec, apps=apps)
+                study = {"trials": trials, "seed": seed,
+                         "s": time.perf_counter() - t0, "stats": {}}
+                digest = hashlib.sha256()
+                for sch in sorted(res.stats):
+                    st = vars(res.stats[sch])
+                    for k in sorted(st):
+                        study["stats"][f"{sch}.{k}"] = sha(st[k])
+                        digest.update(np.ascontiguousarray(st[k]).tobytes())
+                    for field in ("estimates", "errors", "half_widths"):
+                        if sch in getattr(res, field):
+                            study["stats"][f"{sch}.{field}"] = sha(
+                                getattr(res, field)[sch])
+                study["digest"] = digest.hexdigest()
+                out["studies"].append(study)
+                print(f"{out['layout']} {trials} trials, seed {seed}: "
+                      f"{study['s']:.3f} s, digest {study['digest']}",
+                      flush=True)
+        try:
+            from repro.experiments.montecarlo import study_memo
+            memo = study_memo(engine)
+            out["study_memo"] = {"hits": memo.hits, "misses": memo.misses}
+        except ImportError:
+            out["study_memo"] = None
     return out
 
 
 def compare(a: dict, b: dict) -> list[str]:
     diff = [f"build {f}" for f in BUILD_FIELDS
             if a["build"][f] != b["build"][f]]
-    if [s["seed"] for s in a["studies"]] != [s["seed"] for s in b["studies"]]:
+    if ([(s.get("trials"), s["seed"]) for s in a["studies"]]
+            != [(s.get("trials"), s["seed"]) for s in b["studies"]]):
         return diff + ["the two files ran other study seeds"]
     for sa, sb in zip(a["studies"], b["studies"]):
         diff += [f"seed {sa['seed']} {k}" for k in sa["stats"]
@@ -124,7 +140,8 @@ def main(argv=None) -> int:
     r.add_argument("--layout", default="none")
     r.add_argument("--config", default=os.path.join(
         ROOT, "bench", "configs", "spec17int_k20.json"))
-    r.add_argument("--trials", type=int, default=100_000)
+    r.add_argument("--trials", type=lambda s: [int(x) for x in s.split(",")],
+                   default=[100_000])
     r.add_argument("--seeds", type=lambda s: [int(x) for x in s.split(",")],
                    default=[1501, 1502, 1503])
     r.add_argument("--out", required=True)
@@ -140,7 +157,8 @@ def main(argv=None) -> int:
         a, b = json.load(fa), json.load(fb)
     diff = compare(a, b)
     n = sum(len(s["stats"]) + 1 for s in a["studies"]) + len(BUILD_FIELDS)
-    print(f"{a['layout']} vs {b['layout']}: {n - len(diff)} of {n} equal")
+    print(f"{a['layout']} vs {b['layout']}: {n - len(diff)} of {n} equal; "
+          f"study memo {a.get('study_memo')} and {b.get('study_memo')}")
     for d in diff:
         print(f"differs: {d}")
     return 1 if diff else 0

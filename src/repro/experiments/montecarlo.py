@@ -45,8 +45,10 @@ through the engine's charged ``MemoBank`` (paid once, like the historic
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
+import weakref
 from typing import Optional, Sequence
 
 import jax
@@ -60,9 +62,9 @@ from ..core.sampling.types import critical_values
 from ..simcpu import APP_NAMES, stack_ragged
 from .engine import ExperimentEngine, stratum_tables
 
-__all__ = ["SRS_DRAWS", "TRIAL_SCHEMES", "TRIAL_BLOCK", "TrialSpec",
-           "TrialResult", "charged_pool_fill", "run_trials", "trial_key",
-           "trial_uniforms"]
+__all__ = ["SRS_DRAWS", "TRIAL_SCHEMES", "TRIAL_BLOCK", "ResolvedStudy",
+           "StudyMemo", "TrialSpec", "TrialResult", "charged_pool_fill",
+           "run_trials", "study_memo", "trial_key", "trial_uniforms"]
 
 # the plan-less trial scheme: n-unit uniform draws from the census pool
 SRS_DRAWS = "random"
@@ -82,6 +84,10 @@ _DEFAULT_CHUNK = 4096
 # keep dense (A, T) per-trial arrays by default up to this many trials
 # (the Fig 8 regime); past it only the streamed statistics come home
 _KEEP_TRIALS_MAX = 8192
+# resolved studies an engine keeps, the least recently used dropped past
+# it: an entry holds every scheme's tables, about 10 MB at the 10-app
+# bank, on the host and again on the devices
+_STUDY_MEMO_SIZE = 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -526,62 +532,224 @@ def charged_pool_fill(engine: ExperimentEngine, spec: TrialSpec, apps,
         return None
     stack = engine.stack(tuple(apps))
     cfg = engine.configs[spec.config_index]
+    # the (A, n1_max, F) features are gathered only on a miss
     cpi, _ = engine.memo.fill(stack.rows, stack.idx1, stack.idx1_valid,
                               (cfg,),
-                              feats=stack.gather_feats(stack.idx1),
+                              feats=lambda: stack.gather_feats(stack.idx1),
                               mesh=mesh)
     return cpi[:, 0, :]
 
 
-@functools.partial(jax.profiler.annotate_function, name="trials.setup")
-def _scheme_setup(engine: ExperimentEngine, spec: TrialSpec, apps, mesh,
-                  stratifiers: Optional[dict] = None):
-    """Resolve everything a scheme's chunk program consumes on the host.
+@dataclasses.dataclass
+class ResolvedStudy:
+    """Everything a study's streaming programs consume besides its seed.
 
-    Returns ``(truth, pp, setups)`` — the (A,) census truth at the study
-    config, the resolved ``PrecisionPolicy`` and, per scheme, the tuple
-    ``(chunk_fn, draws, crit, tables)`` the streaming program binds.
+    ``truth`` is the (A,) census truth at the study config, ``pp`` the
+    resolved ``PrecisionPolicy``, ``setups`` per scheme the tuple
+    ``(chunk_fn, draws, crit, tables)`` the streaming program binds, and
+    ``pool`` the charged phase-1 pool the tables were built from
+    (``None`` where no scheme draws from one). The program inputs are
+    copied to the devices on first use, once per program mesh, and kept
+    (``device_args``).
+    """
+
+    truth: np.ndarray
+    pp: PrecisionPolicy
+    setups: dict
+    pool: Optional[np.ndarray]
+    _device: dict = dataclasses.field(default_factory=dict, repr=False)
+
+    def _host_args(self, scheme: str) -> dict:
+        """The program's ``(app_ids, truth, crit, *tables)`` for
+        ``scheme`` as host arrays, by their key in the device store."""
+        _, _, crit, tables = self.setups[scheme]
+        host = {"app_ids": np.arange(len(self.truth), dtype=np.int32),
+                "truth": self.truth.astype(self.pp.trace_dtype),
+                (scheme, "crit"): crit}
+        host.update(((scheme, i), t) for i, t in enumerate(tables))
+        return host
+
+    def upload_bytes(self, scheme: str, mesh) -> int:
+        """Bytes ``device_args(scheme, mesh)`` sends to the devices: the
+        inputs not yet resident there (``_h2d_bytes``), 0 once all are."""
+        return _h2d_bytes(tuple(v for k, v in self._host_args(scheme).items()
+                                if (mesh, k) not in self._device), mesh)
+
+    def device_args(self, scheme: str, mesh) -> tuple:
+        """The program's ``(app_ids, truth, crit, *tables)`` for
+        ``scheme`` as device arrays in the layout the program consumes,
+        each sent on its first use under ``mesh`` and kept.
+
+        On one device each array is put as it is; under ``mesh`` its app
+        axis is padded to the app-axis size and placed with the ``P(app)``
+        sharding of ``make_app_trial_sharded``'s inputs, so the program
+        call neither pads nor reshards it.
+        """
+        host = self._host_args(scheme)
+        if mesh is None:
+            put = jax.device_put
+        else:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            from ..distributed.appaxis import app_trial_axes, pad_app_axis
+            app_axis, _ = app_trial_axes(mesh)
+            n_app = int(mesh.shape[app_axis])
+            sharding = NamedSharding(mesh, P(app_axis))
+
+            def put(x):
+                return jax.device_put(pad_app_axis(np.asarray(x), n_app),
+                                      sharding)
+        with self.pp.x64_context():
+            for k, v in host.items():
+                if (mesh, k) not in self._device:
+                    self._device[(mesh, k)] = put(v)
+        return tuple(self._device[(mesh, k)] for k in host)
+
+
+class StudyMemo:
+    """An engine's resolved studies by key, the least recently used
+    dropped past ``_STUDY_MEMO_SIZE``; ``hits`` and ``misses`` count the
+    studies that were and were not served from it."""
+
+    def __init__(self):
+        self._entries: collections.OrderedDict = collections.OrderedDict()
+        self.hits = 0
+        self.misses = 0
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+    def get(self, key) -> Optional[ResolvedStudy]:
+        """The entry under ``key``, now the most recently used, or None."""
+        study = self._entries.get(key)
+        if study is not None:
+            self._entries.move_to_end(key)
+        return study
+
+    def put(self, key, study: ResolvedStudy) -> None:
+        """Keep ``study`` under ``key``, dropping the least recently used
+        entry past the bound."""
+        self._entries[key] = study
+        self._entries.move_to_end(key)
+        while len(self._entries) > _STUDY_MEMO_SIZE:
+            self._entries.popitem(last=False)
+
+
+_STUDY_MEMOS: "weakref.WeakKeyDictionary[ExperimentEngine, StudyMemo]" = \
+    weakref.WeakKeyDictionary()
+
+
+def study_memo(engine: ExperimentEngine) -> StudyMemo:
+    """The ``StudyMemo`` of ``engine`` (made on first use)."""
+    memo = _STUDY_MEMOS.get(engine)
+    if memo is None:
+        memo = _STUDY_MEMOS[engine] = StudyMemo()
+    return memo
+
+
+def _study_key(spec: TrialSpec, apps, mesh, strats: dict,
+               pp: PrecisionPolicy):
+    """What a resolved study depends on beyond its engine's build: apps,
+    config, schemes, CI level, the SRS draw size where SRS runs, the
+    precision, the stratifiers by value and the mesh of the charged
+    fill (the build's k-means seed is always 0 here). ``None`` where a
+    plug-in stratifier cannot be hashed: such a study is not kept."""
+    key = (apps, 0, spec.config_index, spec.schemes, spec.confidence,
+           spec.units_per_trial if SRS_DRAWS in spec.schemes else None,
+           pp, tuple(strats.items()), mesh)
+    try:
+        hash(key)
+    except TypeError:
+        return None
+    return key
+
+
+def _same_bits(a: Optional[np.ndarray], b: Optional[np.ndarray]) -> bool:
+    if a is None or b is None:
+        return a is None and b is None
+    return (a.dtype == b.dtype and a.shape == b.shape
+            and a.tobytes() == b.tobytes())
+
+
+def _scheme_setup(engine: ExperimentEngine, spec: TrialSpec, apps, mesh,
+                  stratifiers: Optional[dict] = None) -> ResolvedStudy:
+    """The ``ResolvedStudy`` of ``spec`` over ``apps``: from the engine's
+    ``StudyMemo`` where it holds the study's key, else resolved here
+    (``_resolve_study``) and kept.
 
     Shared by ``run_trials`` and the resumable driver
-    (``repro.experiments.resumable``) so an interrupted run re-derives
+    (``repro.experiments.resumable``) so an interrupted run binds
     bitwise-identical program inputs: the stratum tables, value pools
-    and critical values are pure functions of the engine build, and the
-    memo fills here are the trial path's ONLY charged work (re-running
-    them after a restore is a pure cache hit, keeping ledger totals
-    path-independent).
+    and critical values are pure functions of the engine build and the
+    key. The charged phase-1 pool fill — the trial path's ONLY charged
+    work — runs in every study, hit or miss, so memo counters and ledger
+    totals are those of resolving every study anew (after a restore, or
+    a memo eviction of the study's config, it charges again); a kept
+    study serves only while the fill's pool is bit for bit the one its
+    tables were built from.
 
-    Profiler spans: ``trials.setup`` around the whole, ``trials.resolve``
-    (the build's banks and the census pool), ``trials.pool_fill`` and,
-    per scheme, ``trials.tables``.
+    Profiler spans: ``trials.setup`` around the whole (arg ``cached``: 1
+    where the memo held the key), ``trials.pool_fill`` and, on a miss
+    only, ``trials.resolve`` (the build's banks and the census pool) and
+    per scheme ``trials.tables``.
     """
+    apps = tuple(apps)
+    pp = resolve_precision(spec.precision, engine.precision)
+    strats = {s: (stratifiers or {}).get(s)
+              or sampling_plan.make_stratifier(s)
+              for s in spec.schemes if s != SRS_DRAWS}
+    memo = study_memo(engine)
+    key = _study_key(spec, apps, mesh, strats, pp)
+    study = None if key is None else memo.get(key)
+
+    def fill():
+        # phase-1 CPI value pool (charged once, via the serving-shared
+        # helper so request dedup can replay the hit)
+        with jax.profiler.TraceAnnotation("trials.pool_fill"):
+            pool = charged_pool_fill(engine, spec, apps, mesh, stratifiers)
+        return None if pool is None else pool.astype(pp.trace_dtype)
+
+    with jax.profiler.TraceAnnotation("trials.setup",
+                                      cached=int(study is not None)):
+        if study is not None:
+            p1_pool = fill()
+            if _same_bits(p1_pool, study.pool):
+                memo.hits += 1
+                return study
+        memo.misses += 1
+        # a kept study whose pool went stale has had this study's fill
+        study = _resolve_study(engine, spec, apps, strats, pp,
+                               fill if study is None else lambda: p1_pool)
+        if key is not None:
+            memo.put(key, study)
+        return study
+
+
+def _resolve_study(engine: ExperimentEngine, spec: TrialSpec, apps,
+                   strats: dict, pp: PrecisionPolicy,
+                   fill) -> ResolvedStudy:
+    """Resolve on the host everything a scheme's chunk program consumes
+    (``ResolvedStudy``), ``fill()`` giving the (A, n1_max) phase-1 pool
+    once the build is resolved."""
     ci = spec.config_index
     l_n = engine.num_strata
-    pp = resolve_precision(spec.precision, engine.precision)
     tdt = pp.trace_dtype
     with jax.profiler.TraceAnnotation("trials.resolve"):
         exps = engine.build(apps)
         stack = engine.stack(apps)
         truth = np.stack([e.truth[ci] for e in exps])
 
-        # registry-resolved stratifications: each scheme name becomes a
-        # Stratifier whose StratumBank declares its labels, weights and
-        # order key — and whose ``pool_kind`` declares the value-pool
-        # cost semantics — no per-scheme branches below
-        strats = {s: (stratifiers or {}).get(s)
-                  or sampling_plan.make_stratifier(s)
-                  for s in spec.schemes if s != SRS_DRAWS}
+        # registry-resolved stratifications: each scheme's Stratifier
+        # resolves to a StratumBank that declares its labels, weights and
+        # order key — and its ``pool_kind`` declares the value-pool cost
+        # semantics — no per-scheme branches below
         banks = {s: strat.resolve(exps) for s, strat in strats.items()}
         charged = {s for s, strat in strats.items()
                    if strat.pool_kind == "phase1"}
         # census CPI value pool (free)
         census, _ = stack_ragged([e.census(ci) for e in exps], dtype=tdt)
 
-    # phase-1 CPI value pool (charged once, via the serving-shared helper
-    # so request dedup can replay the hit)
-    with jax.profiler.TraceAnnotation("trials.pool_fill"):
-        p1_pool = charged_pool_fill(engine, spec, apps, mesh, stratifiers)
-    if p1_pool is not None:
-        p1_pool = p1_pool.astype(tdt)                      # (A, n1_max)
+    p1_pool = fill()
 
     setups: dict[str, tuple] = {}
     for scheme in spec.schemes:
@@ -624,7 +792,7 @@ def _scheme_setup(engine: ExperimentEngine, spec: TrialSpec, apps, mesh,
                                counts.astype(np.int32), weights.astype(tdt),
                                key_order.astype(np.int32)) + groups
                               + (n_occ.astype(np.int32),))
-    return truth, pp, setups
+    return ResolvedStudy(truth=truth, pp=pp, setups=setups, pool=p1_pool)
 
 
 def run_trials(engine: ExperimentEngine, spec: TrialSpec = TrialSpec(),
@@ -666,27 +834,29 @@ def run_trials(engine: ExperimentEngine, spec: TrialSpec = TrialSpec(),
             spec, ntd, 1 if mesh is None else mesh.size)
         keep = (spec.keep_trials if spec.keep_trials is not None
                 else spec.trials <= _KEEP_TRIALS_MAX)
-        app_ids = np.arange(len(apps), dtype=np.int32)
-        truth, pp, setups = _scheme_setup(engine, spec, apps, mesh,
-                                          stratifiers)
-        tdt = pp.trace_dtype
+        study = _scheme_setup(engine, spec, apps, mesh, stratifiers)
+        pp = study.pp
 
         stats: dict[str, sampling_tables.TrialStats] = {}
         estimates: dict[str, np.ndarray] = {}
         errors: dict[str, np.ndarray] = {}
         halves: dict[str, np.ndarray] = {}
         for scheme in spec.schemes:
-            chunk_fn, draws, crit, tables = setups[scheme]
+            chunk_fn, draws, _, _ = study.setups[scheme]
             program = _streaming_program(
                 chunk_fn, mesh, kb=kb, n_chunks=n_chunks, trials=spec.trials,
                 draws=draws, trace=pp.trace, accum=pp.accum, keep=keep)
             with pp.x64_context():
-                host = (app_ids, truth.astype(tdt), crit, *tables)
+                key, chunk0 = trial_key(spec, scheme), np.int32(0)
+                # the inputs not yet on the devices, then the key and
+                # chunk0 to every device
+                sent = study.upload_bytes(scheme, mesh) + (
+                    key.nbytes + chunk0.nbytes) * (
+                        1 if mesh is None else mesh.size)
                 with jax.profiler.TraceAnnotation(
-                        "trials.dispatch", scheme=scheme,
-                        h2d_bytes=_h2d_bytes(host, mesh)):
-                    out = program(trial_key(spec, scheme), np.int32(0),
-                                  *host)
+                        "trials.dispatch", scheme=scheme, h2d_bytes=sent):
+                    out = program(key, chunk0,
+                                  *study.device_args(scheme, mesh))
             with jax.profiler.TraceAnnotation("trials.fetch", scheme=scheme):
                 stats[scheme], ys = _fetch_streaming_out(out, len(apps))
                 if keep:

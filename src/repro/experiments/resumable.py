@@ -244,10 +244,10 @@ def run_trials_resumable(engine: ExperimentEngine,
     quanta = [(scheme, c0, nc)
               for scheme in spec.schemes for (c0, nc) in segments]
 
-    truth, pp, setups = _scheme_setup(engine, spec, apps, mesh, None)
+    study = _scheme_setup(engine, spec, apps, mesh, None)
+    pp = study.pp
     tdt = pp.trace_dtype
     a_n = len(apps)
-    app_ids = np.arange(a_n, dtype=np.int32)
     t_pad = n_chunks * kb * TRIAL_BLOCK
 
     run_id = {"kind": "trials", "apps": list(apps),
@@ -290,13 +290,13 @@ def run_trials_resumable(engine: ExperimentEngine,
     for q in range(start, len(quanta)):
         t0 = time.perf_counter()
         scheme, c0, nc = quanta[q]
-        chunk_fn, draws, crit, tables = setups[scheme]
+        chunk_fn, draws, _, _ = study.setups[scheme]
         program = _streaming_program(
             chunk_fn, prog_mesh, kb=kb, n_chunks=nc, trials=spec.trials,
             draws=draws, trace=pp.trace, accum=pp.accum, keep=keep_dense)
         with pp.x64_context():
             out = program(trial_key(spec, scheme), np.int32(c0),
-                          app_ids, truth.astype(tdt), crit, *tables)
+                          *study.device_args(scheme, prog_mesh))
         st, ys = _fetch_streaming_out(out, a_n)
         stats[scheme] = sampling_tables.trial_stats_merge(stats[scheme], st)
         if keep_dense:

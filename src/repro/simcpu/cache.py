@@ -221,10 +221,12 @@ class MemoBank:
 
         ``rows``: (R,) app rows; ``idx``: (R, K) region indices (padding
         allowed, flagged invalid in ``valid``); ``feats``: (R, K, F)
-        gathered features, evaluated in ONE vmapped dispatch (app-sharded
-        when ``mesh`` is given) — or ``values``: (R, C, K) precomputed CPI
-        (full-stats path). Returns ``(cpi, n_miss)`` with ``n_miss`` the
-        per-(row, config) newly-charged region counts.
+        gathered features, or a callable that returns them, called only
+        when a requested region misses — evaluated in ONE vmapped
+        dispatch (app-sharded when ``mesh`` is given) — or ``values``:
+        (R, C, K) precomputed CPI (full-stats path). Returns ``(cpi,
+        n_miss)`` with ``n_miss`` the per-(row, config) newly-charged
+        region counts.
         """
         rows = np.asarray(rows, np.int64)
         idx = np.asarray(idx, np.int64)
@@ -253,6 +255,8 @@ class MemoBank:
             return out, n_miss
 
         if values is None:
+            if callable(feats):
+                feats = feats()
             values = cpi_bank(feats, cfgs, mesh=mesh)      # (R, C, K)
         values = np.asarray(values, np.float32)
 

@@ -138,14 +138,56 @@ def engine():
     return ExperimentEngine()
 
 
+@pytest.fixture(scope="module")
+def traced_twice(engine, tmp_path_factory):
+    """The spans of two studies of one key on one engine, at two seeds:
+    the first resolves the study, the second is served from the memo."""
+    trace_dir = str(tmp_path_factory.mktemp("trace_twice"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(trace_dir, profiler_options=opts):
+        for seed in (31, 32):
+            run_trials(engine, TrialSpec(trials=512, seed=seed), apps=APPS2)
+    spans = _host_spans(trace_dir, ("trials.",))
+    runs = sorted((s for s in spans if s["name"] == "trials.run"),
+                  key=lambda s: s["start"])
+    return [[s for s in spans if _inside(s, run)] for run in runs]
+
+
+def test_setup_span_says_whether_the_memo_served_it(traced_twice):
+    """``trials.setup`` carries ``cached`` 0 on the first study and 1 on
+    the second; only the first resolves and builds tables, both replay
+    the charged fill."""
+    assert len(traced_twice) == 2
+    for cached, spans in enumerate(traced_twice):
+        names = [s["name"] for s in spans]
+        setup = [s for s in spans if s["name"] == "trials.setup"]
+        assert [s["args"] for s in setup] == [{"cached": cached}]
+        assert names.count("trials.pool_fill") == 1
+        assert names.count("trials.resolve") == 1 - cached
+        assert names.count("trials.tables") == (1 - cached) * len(
+            TRIAL_SCHEMES)
+
+
+def test_h2d_bytes_on_a_hit_count_only_the_key_and_chunk0(traced_twice):
+    """Served from the memo, a dispatch sends its PRNG key (two uint32)
+    and ``chunk0`` (one int32): the tables stay on the device."""
+    first, second = ([s["args"]["h2d_bytes"] for s in spans
+                      if s["name"] == "trials.dispatch"]
+                     for spans in traced_twice)
+    assert second == [12] * len(TRIAL_SCHEMES)
+    assert all(b > 12 for b in first)
+
+
 @pytest.mark.parametrize("scheme", ["random", "dg"])
 def test_streaming_program_hlo_carries_scan_scopes(engine, scheme):
     """Both chunk functions' compiled scans name every stage in their
     ``op_name`` metadata, the SRS t-interval and the stratified
     collapsed-pairs alike."""
     spec = TrialSpec(trials=512, schemes=(scheme,), chunk_size=256)
-    truth, pp, setups = _scheme_setup(engine, spec, APPS2, None)
-    chunk_fn, draws, crit, tables = setups[scheme]
+    study = _scheme_setup(engine, spec, APPS2, None)
+    pp = study.pp
+    chunk_fn, draws, crit, tables = study.setups[scheme]
     kb, n_chunks = _chunk_blocks(spec, 1)
     program = _streaming_program(
         chunk_fn, None, kb=kb, n_chunks=n_chunks, trials=spec.trials,
@@ -153,7 +195,8 @@ def test_streaming_program_hlo_carries_scan_scopes(engine, scheme):
     tdt = resolve_precision(spec.precision, engine.precision).trace_dtype
     hlo = program.lower(trial_key(spec, scheme), np.int32(0),
                         np.arange(len(APPS2), dtype=np.int32),
-                        truth.astype(tdt), crit, *tables).compile().as_text()
+                        study.truth.astype(tdt), crit,
+                        *tables).compile().as_text()
     for scope in SCAN_SCOPES:
         assert re.search(r'op_name="[^"]*/' + re.escape(scope) + "/", hlo), \
             scope
