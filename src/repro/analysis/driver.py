@@ -1,7 +1,7 @@
 """jaxlint driver: discovery, rule dispatch, baseline, output, CLI.
 
-``run_lint`` is the library surface (the fixture tests and the bench
-claim row call it in-process); ``main`` is the CLI behind both
+``run_lint`` is the library surface (the fixture tests call it
+in-process); ``main`` is the CLI behind both
 ``python -m repro.analysis`` and ``scripts/lint.py``.
 
 Exit codes: 0 clean (baselined findings included), 1 active findings /
@@ -25,9 +25,9 @@ __all__ = ["LintReport", "run_lint", "main", "DEFAULT_ROOTS",
            "DEFAULT_BASELINE"]
 
 # scanned by default, relative to the repo root: the package itself,
-# plus the bench/example/script code the PRNG- and trace-discipline
-# rules must sweep (key reuse historically hides in driver scripts)
-DEFAULT_ROOTS = ("src/repro", "benchmarks", "examples", "scripts")
+# plus the example/script code the PRNG- and trace-discipline rules must
+# sweep (key reuse historically hides in driver scripts)
+DEFAULT_ROOTS = ("src/repro", "examples", "scripts")
 DEFAULT_BASELINE = "lint_baseline.json"
 
 

@@ -78,8 +78,8 @@ class KMeansResult:
     """One fitted stratification.
 
     ``backend`` records the assignment backend that actually ran
-    (``resolve_backend``'s ``active`` value), so benchmarks/tests can
-    assert which path produced the fit.
+    (``resolve_backend``'s ``active`` value), so tests can assert which
+    path produced the fit.
     """
 
     centroids: np.ndarray   # (k, d)
@@ -99,14 +99,6 @@ def _assign_jnp_stacked(x: jax.Array, centroids: jax.Array
     d2 = x2 - 2.0 * xc + c2[:, None, :]
     labels = jnp.argmin(d2, axis=2)
     return labels, jnp.maximum(jnp.min(d2, axis=2), 0.0)
-
-
-def _assign_jnp(x: jax.Array, centroids: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Nearest-centroid assignment, one ``(n, d)`` problem: lane 0 of the
-    stacked oracle (single source of truth for the distance formulation).
-    Kept for host-side callers (``repro.core.clustering.distributed``)."""
-    labels, min_d2 = _assign_jnp_stacked(x[None], centroids[None])
-    return labels[0], min_d2[0]
 
 
 def _assign_pallas_stacked(x: jax.Array, centroids: jax.Array

@@ -95,8 +95,8 @@ class PrecisionPolicy:
     def host_parity(cls) -> "PrecisionPolicy":
         """The sweep-estimate policy: trace in the host dtype off-TPU so
         on-device estimates match the numpy reference bitwise (f64 on CPU
-        hosts), f32 trace on TPU where f64 is emulated and the parity
-        tolerance widens instead (``benchmarks/run.py``)."""
+        hosts), f32 trace on TPU where f64 is emulated, so estimates
+        there match the reference to f32 rounding only."""
         import jax
         if jax.default_backend() == "tpu":
             return cls(trace="float32", accum="float32", host="float64")

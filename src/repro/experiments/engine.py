@@ -249,7 +249,7 @@ class ExperimentEngine:
     @classmethod
     def auto(cls, **kwargs) -> "ExperimentEngine":
         """Engine with an ``("app",)`` mesh when >1 device is present —
-        THE way examples/benchmarks pick up ``--devices N`` /
+        the way ``repro.paper`` and the examples pick up
         ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
         if "mesh" not in kwargs:
             mesh = None

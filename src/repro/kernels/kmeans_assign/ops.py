@@ -9,8 +9,8 @@ the kernel body on CPU; TPU is the compile target).
 
 ``last_dispatch()`` exposes a trace-time marker describing the most
 recent kernel dispatch (batch size, grid, block shape, interpret flag) so
-tests and benchmarks can assert the batch-native path was taken rather
-than a lifted/vmapped one.
+tests can assert the batch-native path was taken rather than a
+lifted/vmapped one.
 """
 
 from __future__ import annotations

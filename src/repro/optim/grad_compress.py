@@ -8,9 +8,8 @@ next step, which keeps SGD/Adam convergence (Seide et al. / EF-SGD).
 
 Under GSPMD the reduction itself is implicit, so this transform models the
 production path as quantize -> dequantize around the gradient use, with the
-EF state threaded through the optimizer. The collective-byte savings are
-counted in the roofline analysis (benchmarks/roofline.py) as a
-bytes-on-the-"pod"-axis reduction factor.
+EF state threaded through the optimizer. The collective-byte saving is
+``Int8EF.BYTES_FACTOR``, a bytes-on-the-"pod"-axis reduction factor.
 """
 
 from __future__ import annotations

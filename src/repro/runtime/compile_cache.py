@@ -1,10 +1,10 @@
 """JAX's persistent compilation cache at a place the caller can fix.
 
-Every entry point (``chip_smoke.py``, ``benchmarks/run.py``,
-``python -m repro.serving.cli`` and the examples) calls
-``enable_compile_cache()`` before its first compile, so a second run
-with the same programs loads them instead of compiling again. Library
-imports and the test suite never turn the cache on.
+Every entry point (``chip_smoke.py``, ``bench/run.py``,
+``python -m repro.paper``, ``python -m repro.serving.cli`` and the
+examples) calls ``enable_compile_cache()`` before its first compile, so
+a second run with the same programs loads them instead of compiling
+again. Library imports and the test suite never turn the cache on.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; the
   directory is not set in code.

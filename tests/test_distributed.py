@@ -1,4 +1,4 @@
-"""Sharding rules / mesh / distributed-clustering tests (host mesh)."""
+"""Sharding rules and mesh helper tests (host mesh)."""
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +8,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config
 from repro.distributed.sharding import (batch_specs, cache_specs,
                                         opt_state_specs, param_specs)
-from repro.launch.mesh import data_axes, make_host_mesh
+from repro.launch.mesh import data_axes
 from repro.models.registry import init_params, make_decode_state
 
 
@@ -87,21 +87,6 @@ def test_batch_and_cache_specs():
 def test_data_axes_helper():
     assert data_axes(MESH_MULTI) == ("pod", "data")
     assert data_axes(MESH) == ("data",)
-
-
-def test_distributed_kmeans_matches_quality():
-    from repro.core.clustering import kmeans
-    from repro.core.clustering.distributed import distributed_kmeans
-    rng = np.random.default_rng(0)
-    x = np.concatenate([rng.normal(4.0 * i, 0.3, (400, 8))
-                        for i in range(4)]).astype(np.float32)
-    mesh = make_host_mesh()
-    _, labels, inertia = distributed_kmeans(x, 4, mesh, iters=20)
-    ref = kmeans(x, 4, seed=0)
-    assert inertia <= ref.inertia * 1.3
-    labels = np.asarray(labels).reshape(4, 400)
-    for i in range(4):
-        assert len(np.unique(labels[i])) == 1
 
 
 def test_activation_constrain_noop_off_mesh():

@@ -19,7 +19,7 @@ import pytest
 
 from repro.core.clustering import kmeans_bank
 from repro.core.sampling import (Centroid, DaleniusGurney, RandomUnit,
-                                 SamplingPlan)
+                                 RFVClusters, SamplingPlan)
 from repro.experiments import (ExperimentEngine, SweepSpec, TrialSpec,
                                plan_selection, run_sweep, run_trials,
                                trial_uniforms)
@@ -318,6 +318,75 @@ def test_sharded_engine_matches_single_host():
     for e1, e2 in zip(single.build(APPS2), sharded.build(APPS2)):
         assert e1.sim.ledger.regions_simulated == \
             e2.sim.ledger.regions_simulated
+
+
+
+def _ledgers(engine):
+    return [None if l is None else l.regions_simulated
+            for l in engine.memo.ledgers]
+
+
+def _app_mesh_engines(n):
+    from repro.launch.mesh import make_app_mesh
+    return [ExperimentEngine(mesh=make_app_mesh()) for _ in range(n)]
+
+
+@needs_devices
+def test_sharded_fused_sweep_matches_staged():
+    """On the ("app",) mesh the fused megaprogram agrees with the staged
+    chain: estimates to 1e-6, charge matrix and ledgers exactly."""
+    fused, staged = _app_mesh_engines(2)
+    spec = SweepSpec(apps=APPS2, plan=SamplingPlan(RFVClusters(), Centroid()))
+    tf = run_sweep(fused, spec)
+    ts = run_sweep(staged, dataclasses.replace(spec, fused=False))
+    np.testing.assert_allclose(tf.column("estimate"), ts.column("estimate"),
+                               rtol=1e-6)
+    np.testing.assert_array_equal(fused.memo.charges, staged.memo.charges)
+    assert _ledgers(fused) == _ledgers(staged)
+
+
+@needs_devices
+def test_sharded_coalesced_matches_serial_bitwise():
+    """On the ("app",) mesh, eight coalesced same-shape sweeps equal
+    eight serial ``run_sweep`` calls bitwise, ledgers included."""
+    from repro.serving import run_coalesced_sweeps
+
+    serial_eng, coal_eng = _app_mesh_engines(2)
+    plan = SamplingPlan(RFVClusters(), RandomUnit())
+    specs = [SweepSpec(apps=APPS2, plan=plan, config_indices=(0, 1, 2),
+                       selection_seed=s) for s in range(8)]
+    serial = [run_sweep(serial_eng, s) for s in specs]
+    coal = run_coalesced_sweeps(coal_eng, specs)
+    for st, ct in zip(serial, coal):
+        for col in ("estimate", "err_pct", "truth", "n_units"):
+            np.testing.assert_array_equal(
+                np.asarray(st.column(col), float),
+                np.asarray(ct.column(col), float))
+    assert _ledgers(serial_eng) == _ledgers(coal_eng)
+
+
+@needs_devices
+def test_sharded_service_memo_cap_bounds_residency():
+    """On the ("app",) mesh a capped, spilling service keeps resident
+    memo columns at the cap after every tick and charges what an
+    uncapped service charges."""
+    from repro.serving import SweepService
+
+    capped_eng, free_eng = _app_mesh_engines(2)
+    plan = SamplingPlan(RFVClusters(), Centroid())
+    specs = [SweepSpec(apps=APPS2, plan=plan, config_indices=cfg_is)
+             for cfg_is in ((0, 1, 2), (3, 4, 5), (0, 1, 2))]
+    capped = SweepService(capped_eng, memo_cap=2, spill=True)
+    for spec in specs:
+        capped.submit(spec)
+        capped.tick()
+        assert len(capped_eng.memo.resident_columns()) <= 2
+    assert capped.stats().evicted_cols > 0
+    free = SweepService(free_eng)
+    for spec in specs:
+        free.submit(spec)
+    free.drain()
+    assert _ledgers(capped_eng) == _ledgers(free_eng)
 
 
 # ------------------------------------------------ mesh paths, always run
